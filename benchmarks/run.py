@@ -786,7 +786,10 @@ def bench_grad_compress():
 
 
 def main(argv=None) -> None:
+    import jax
+
     from repro.core import patterns
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="fast CI subset")
@@ -807,6 +810,7 @@ def main(argv=None) -> None:
         "cache.*) as JSON after the run",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = None
     if args.trace:
@@ -856,6 +860,11 @@ def main(argv=None) -> None:
             "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
             "smoke": bool(args.smoke),
             "platform": platform.platform(),
+            "device": {
+                "platform": jax.devices()[0].platform,
+                "kind": jax.devices()[0].device_kind,
+                "count": len(jax.devices()),
+            },
             "python": platform.python_version(),
             "rows": _ROWS,
         }

@@ -26,12 +26,25 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+import jax.numpy as jnp
+import numpy as np
+
 from ..core.pqir import Graph
 from ..kernels import ops as kops
 from ..obs import trace as _trace
 from ..obs.provenance import PlanProvenance
 from ..passes.analysis import BATCH_AXIS, GraphAnalysis, bind
-from .plan import CONST, NONE, SLOT, Arg, ExecutionPlan, PlanStep, StateBinding, ValueInfo
+from .plan import (
+    CONST,
+    NONE,
+    PARAM_MIN_SIZE,
+    SLOT,
+    Arg,
+    ExecutionPlan,
+    PlanStep,
+    StateBinding,
+    ValueInfo,
+)
 
 #: Draft operand kinds: ("tensor", name) | ("const", value) | ("none", None)
 DraftArg = Tuple[str, Any]
@@ -144,6 +157,11 @@ def build_plan(
             if uses.get(o, 0) == 0:
                 release(o)
         out_info = tuple(ValueInfo(analysis.dtype(o), analysis.shape(o)) for o in d.outputs)
+        # parameters go to the device once, shared by every specialization
+        consts = [
+            jnp.asarray(c) if isinstance(c, np.ndarray) and c.size >= PARAM_MIN_SIZE else c
+            for c in consts
+        ]
         steps.append(
             PlanStep(
                 kernel=d.kernel,

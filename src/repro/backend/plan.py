@@ -59,6 +59,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+import jax
 import numpy as np
 
 from ..core.cache import LruCache
@@ -66,6 +67,13 @@ from ..obs.provenance import PlanProvenance
 
 #: Arg kinds.
 SLOT, CONST, NONE = "slot", "const", "none"
+
+#: Consts of at least this many elements (weights, biases, scales, embedding
+#: tables) reach a jitted executor as arguments; smaller ones (shape operands,
+#: scalars, 256-entry LUTs) stay compile-time constants.  A closed-over
+#: parameter would be embedded in the program as a literal: compiled, and
+#: held on the device, once per specialization.
+PARAM_MIN_SIZE = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,8 +235,29 @@ class ExecutionPlan:
     states: Tuple[StateBinding, ...] = ()
 
     # -- execution -----------------------------------------------------------
-    def execute(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
-        """Slot-indexed interpretation (the hot path; jit-able as a whole)."""
+    def params(self) -> Dict[int, Dict[int, Any]]:
+        """The plan's parameter arrays, ``{step index: {const index: array}}``:
+        every const of at least :data:`PARAM_MIN_SIZE` elements."""
+        out: Dict[int, Dict[int, Any]] = {}
+        for i, step in enumerate(self.steps):
+            big = {
+                j: c for j, c in enumerate(step.consts)
+                if getattr(c, "size", 0) >= PARAM_MIN_SIZE
+            }
+            if big:
+                out[i] = big
+        return out
+
+    def jit(self) -> "JittedPlan":
+        """A jitted executor that passes :meth:`params` as arguments."""
+        return JittedPlan(self)
+
+    def execute(
+        self, feeds: Dict[str, Any], params: Optional[Dict[int, Dict[int, Any]]] = None
+    ) -> Dict[str, Any]:
+        """Slot-indexed interpretation (the hot path; jit-able as a whole).
+        ``params`` (shaped like :meth:`params`) stands in for the consts it
+        names — traced arguments under :meth:`jit`."""
         from .registry import lookup
 
         if self.batch == "dynamic":
@@ -241,7 +270,12 @@ class ExecutionPlan:
         env: List[Any] = [None] * self.num_slots
         for name, slot in self.inputs:
             env[slot] = feeds[name]
-        for step in self.steps:
+        for i, step in enumerate(self.steps):
+            if params and i in params:
+                given = params[i]
+                step = dataclasses.replace(
+                    step, consts=tuple(given.get(j, c) for j, c in enumerate(step.consts))
+                )
             impl = lookup(self.backend, step.kernel)
             args = [
                 env[a.index] if a.kind == SLOT
@@ -330,6 +364,23 @@ class ExecutionPlan:
             f"ExecutionPlan(backend={self.backend!r}, steps={len(self.steps)}, "
             f"slots={self.num_slots}, kinds={self.kinds}{batch})"
         )
+
+
+class JittedPlan:
+    """``jax.jit`` of :meth:`ExecutionPlan.execute` with the plan's
+    parameters (:meth:`ExecutionPlan.params`) passed as arguments, so the
+    compiled program holds no copy of the weights.  Called like the plan:
+    ``executor(feeds) -> outputs``."""
+
+    def __init__(self, plan: ExecutionPlan) -> None:
+        self.params = plan.params()
+        self._fn = jax.jit(plan.execute)
+
+    def __call__(self, feeds: Dict[str, Any]) -> Dict[str, Any]:
+        return self._fn(feeds, self.params)
+
+    def lower(self, feeds: Dict[str, Any]):
+        return self._fn.lower(feeds, self.params)
 
 
 # ---------------------------------------------------------------------------

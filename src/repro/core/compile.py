@@ -993,7 +993,7 @@ class CompiledModel:
             self.axis_input_pos = {}
             self.output_axis_positions = {}
             self.output_axis_pos = {}
-            self._jitted = jax.jit(self._execute)
+            self._jitted = self.plan.jit()
 
     @property
     def backend(self) -> str:
@@ -1013,9 +1013,6 @@ class CompiledModel:
     def batch_output_names(self) -> set:
         """Outputs carrying the batch axis (PR 4 compat view)."""
         return {k for k, v in self.output_axis_pos.items() if BATCH_AXIS in v}
-
-    def _execute(self, feeds: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-        return self.plan.execute(feeds)
 
     def run(self, feeds: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         if self.is_dynamic:
@@ -1073,7 +1070,7 @@ class CompiledModel:
         entry = self.plan_cache.get(key)
         if entry is None:
             plan = specialize_plan(self.plan, bindings, tuner=self.autotuner)
-            entry = (plan, jax.jit(plan.execute))
+            entry = (plan, plan.jit())
             self.plan_cache.put(key, entry)
         return entry
 

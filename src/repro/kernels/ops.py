@@ -358,9 +358,10 @@ def quantized_activation(
     lut: jax.Array | np.ndarray,  # (256,) int8/uint8
     *,
     backend: str = "ref",
-    one_hot: bool = False,
 ) -> jax.Array:
-    """int8 LUT activation over arbitrary shape."""
+    """int8 LUT activation over arbitrary shape: flattened to ``(M, N)`` and
+    zero-padded to the kernel's blocks (any code is a valid index; the
+    padding is sliced away)."""
     lut = jnp.asarray(lut)
     if backend == "ref":
         return _ref.qact_lut_ref(x_q, lut)
@@ -368,9 +369,12 @@ def quantized_activation(
     n = orig_shape[-1]
     x2 = x_q.reshape(-1, n)
     m = x2.shape[0]
-    bm = min(512, m) if m % min(512, m) == 0 else m
-    out = _qact.qact_lut(x2, lut, block=bm, one_hot=one_hot, interpret=(backend == "interpret"))
-    return out.reshape(orig_shape)
+    bm, bn = _qact.choose_blocks(m, n)
+    mp, np_ = _round_up(max(m, 1), bm), _round_up(n, bn)
+    if (mp, np_) != (m, n):
+        x2 = jnp.pad(x2, ((0, mp - m), (0, np_ - n)))
+    out = _qact.qact_lut(x2, lut, bm=bm, bn=bn, interpret=(backend == "interpret"))
+    return out[:m, :n].reshape(orig_shape)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "relu", "two_mul", "strides", "pads"))

@@ -1,4 +1,4 @@
-"""int8 activation as an exact 256-entry VMEM lookup table (Pallas TPU kernel).
+"""int8 activation as an exact 256-entry lookup table (Pallas TPU kernel).
 
 TPU-native adaptation of the paper's §6 activation flows (DESIGN.md §3): the
 artifact codifies ``QuantizeLinear → DequantizeLinear → [Cast f16] →
@@ -9,10 +9,11 @@ Figs 5/6) into a 256-entry table, making the kernel bit-exact against the
 reference interpreter by construction while eliminating all transcendental
 work on-chip.
 
-The table lives permanently in VMEM (256 B); the lookup is a VPU gather
-(``jnp.take``).  On hardware generations where Mosaic lacks a fast dynamic
-gather, set ``one_hot=True`` to lower the lookup as an int8 one-hot matmul on
-the MXU (`one_hot(idx)·lut`), which is mathematically identical.
+Mosaic lowers no 1-D gather, so the lookup (:func:`lut_lookup`, shared with
+the fused attention kernel) is a compare-and-select chain over the table,
+held 2-D as ``(1, 256)`` int32 in SMEM: each step broadcasts one scalar
+entry into the lanes whose index matches it.  It is exact for any int8 or
+uint8 table, since every value passes through int32 untouched.
 """
 from __future__ import annotations
 
@@ -22,6 +23,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .qmatmul import MIN_LANE, _ceil_to, choose_bm
+
+LUT_SIZE = 256
+
+#: Default (rows, lanes) block of the activation kernel.
+BM, BN = 256, 1024
 
 
 def build_lut(fn, in_scale: float, out_scale: float, out_dtype: str = "int8", compute_dtype: str = "float32") -> np.ndarray:
@@ -38,44 +47,57 @@ def build_lut(fn, in_scale: float, out_scale: float, out_dtype: str = "int8", co
     return np.clip(q, info.min, info.max).astype(out_dtype)
 
 
-def _lut_kernel(x_ref, lut_ref, o_ref, *, one_hot: bool):
+def lut_table(lut: jax.Array) -> jax.Array:
+    """A ``(256,)`` int8/uint8 table as the ``(1, 256)`` int32 operand that
+    :func:`lut_lookup` reads."""
+    return jnp.asarray(lut).astype(jnp.int32).reshape(1, LUT_SIZE)
+
+
+#: BlockSpec of the table operand: the whole ``(1, 256)`` array in SMEM.
+LUT_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def lut_lookup(idx: jax.Array, lut_ref) -> jax.Array:
+    """``lut[idx]`` for int32 ``idx`` in [0, 256), inside a kernel: a
+    compare-and-select chain over the SMEM table ref (see :data:`LUT_SPEC`).
+    Returns int32; every index matches exactly one step."""
+
+    def step(c, acc):
+        return jnp.where(idx == c, lut_ref[0, c], acc)
+
+    return jax.lax.fori_loop(0, LUT_SIZE, step, jnp.zeros_like(idx))
+
+
+def _lut_kernel(x_ref, lut_ref, o_ref):
     idx = x_ref[...].astype(jnp.int32) + 128
-    if one_hot:
-        # MXU path: one-hot int8 matmul against the 256-entry table.
-        oh = (idx[..., None] == jax.lax.iota(jnp.int32, 256)).astype(jnp.int8)
-        flat = oh.reshape(-1, 256)
-        vals = jax.lax.dot_general(
-            flat, lut_ref[...].astype(jnp.int8).reshape(256, 1),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32,
-        )
-        o_ref[...] = vals.reshape(idx.shape).astype(o_ref.dtype)
-    else:
-        o_ref[...] = jnp.take(lut_ref[...], idx).astype(o_ref.dtype)
+    o_ref[...] = lut_lookup(idx, lut_ref).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "one_hot", "interpret"))
+def choose_blocks(m: int, n: int, *, bm: int = BM, bn: int = BN):
+    """(bm, bn) for an ``(m, n)`` activation: rows shrink toward ``m`` in
+    sublane steps, lanes take the largest 128-multiple up to ``bn`` that
+    divides ``n`` padded to 128.  The caller pads to the blocks."""
+    return choose_bm(max(m, 1), bm=bm), int(np.gcd(_ceil_to(n, MIN_LANE), bn))
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def qact_lut(
-    x_q: jax.Array,  # (M, N) int8
+    x_q: jax.Array,  # (M, N) int8, M % bm == 0, N % bn == 0
     lut: jax.Array,  # (256,) int8/uint8
     *,
-    block: int = 512,
-    one_hot: bool = False,
+    bm: int = BM,
+    bn: int = BN,
     interpret: bool = False,
 ) -> jax.Array:
-    """Apply an int8→int8/uint8 LUT activation.  Rows must be a multiple of
-    ``block`` or smaller than it (wrapper in ops.py pads)."""
+    """Apply an int8→int8/uint8 LUT activation over ``(bm, bn)`` blocks
+    (:func:`repro.kernels.ops.quantized_activation` pads to them)."""
     m, n = x_q.shape
-    bm = min(block, m)
-    assert m % bm == 0, (m, bm)
-    kernel = functools.partial(_lut_kernel, one_hot=one_hot)
+    assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
     return pl.pallas_call(
-        kernel,
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, n), lambda i: (i, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
+        _lut_kernel,
+        grid=(m // bm, n // bn),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)), LUT_SPEC],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), lut.dtype),
         interpret=interpret,
-    )(x_q, lut)
+    )(x_q, lut_table(lut))

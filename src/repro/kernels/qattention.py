@@ -8,7 +8,7 @@ One kernel realizes the PQ-IR attention region the token path codifies
       → masked shift: s·mask + (mask-1)·big          (additive {0,-big} mask)
       → ReduceMax / Sub                              (softmax max-shift)
       → QuantizeLinear(lut_scale)                    (int8 score deltas)
-      → exp via 256-entry LUT gather                 (VPU, no transcendentals)
+      → exp via 256-entry LUT lookup                 (VPU, no transcendentals)
       → ReduceSum int32 / Div / Mul(p_scale) / QL    (int8 probabilities)
       → MatMulInteger (int8 P × int8 V → int32)      (MXU again)
       → Cast f32 → Mul rescale → QuantizeLinear      (int8 context)
@@ -21,9 +21,10 @@ its double-rate int8 throughput.  Nothing round-trips to HBM between the
 two matmuls — that is the whole point of fusing the region.
 
 Bit-exactness: every step is integer arithmetic or an IEEE-exact f32
-elementwise op in the artifact's codified order, so
-``reference runtime == qattention_ref == qattention(interpret=True)``
-bit-for-bit.  Zero padding is exact end-to-end: padded keys carry a zero
+elementwise op in the artifact's codified order (the TPU's inexact f32
+divide is corrected to IEEE by :func:`repro.kernels.ref.div_rn`), so
+``reference runtime == qattention_ref == qattention`` bit-for-bit, in
+interpret mode and on the chip.  Zero padding is exact end-to-end: padded keys carry a zero
 mask, which drives their score to ``-big`` and their LUT weight to exactly
 ``lut[0] == 0`` (asserted by ``repro.core.patterns.build_exp_lut``), so they
 contribute nothing to the denominator or the context; padded query rows are
@@ -37,7 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .qact_lut import LUT_SPEC, lut_lookup, lut_table
 from .qmatmul import MIN_LANE, MIN_SUBLANE
+from .ref import div_rn
 
 #: Default query row-block.
 BQ = 128
@@ -70,8 +73,8 @@ def _qattention_kernel(
 
     # int8 Q × K^T → int32 scores on the MXU.
     acc = jax.lax.dot_general(
-        q.astype(jnp.int32),
-        k.astype(jnp.int32),
+        q,
+        k,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -79,14 +82,15 @@ def _qattention_kernel(
     masked = s_f * mask + (mask - 1.0) * big
     mx = jnp.max(masked, axis=-1, keepdims=True)
     d_q = jnp.clip(jnp.rint((masked - mx) / lut_scale), -128, 127).astype(jnp.int32)
-    w = jnp.take(lut_ref[...], d_q + 128)  # uint8; masked keys hit lut[0] == 0
-    den = jnp.sum(w.astype(jnp.int32), axis=-1, keepdims=True)
-    p = w.astype(jnp.float32) / den.astype(jnp.float32)
-    p_q = jnp.clip(jnp.rint(p * p_scale), -128, 127).astype(jnp.int32)
+    w = lut_lookup(d_q + 128, lut_ref)  # uint8 values; masked keys hit lut[0] == 0
+    den = jnp.sum(w, axis=-1, keepdims=True)
+    p = div_rn(w.astype(jnp.float32), den.astype(jnp.float32))  # IEEE on the TPU too
+    # p_q is clipped to [-128, 127], so the int8 cast is exact.
+    p_q = jnp.clip(jnp.rint(p * p_scale), -128, 127).astype(jnp.int8)
     # int8 P × V → int32 context on the MXU.
     ctx = jax.lax.dot_general(
         p_q,
-        v.astype(jnp.int32),
+        v,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -147,10 +151,10 @@ def qattention(
             pl.BlockSpec((1, tp, dp), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, tp, dp), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, bq, tp), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((256,), lambda i, j: (0,)),
+            LUT_SPEC,
         ],
         out_specs=pl.BlockSpec((1, bq, dp), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sp, dp), out_dtype),
         interpret=interpret,
-    )(q_q, k_q, v_q, mask, lut)
+    )(q_q, k_q, v_q, mask, lut_table(lut))
     return out[:, :s, :dh]

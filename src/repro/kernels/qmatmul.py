@@ -129,13 +129,15 @@ def _unpack_int4_rows(p):
     """(rows, bn) uint8 nibble-pairs → (2·rows, bn) int8, K-interleaved.
 
     Mirrors :func:`repro.kernels.pack.unpack_int4` with pure VPU shift
-    arithmetic: the low nibble sign-extends via ``int8(p << 4) >> 4``, the
-    high nibble via ``int8(p) >> 4`` (conversion wraps mod 2⁸, then the
-    arithmetic right shift carries the sign).  The stack-reshape interleaves
-    along the sublane axis only — the 128-lane layout is untouched."""
-    lo = (p << 4).astype(jnp.int8) >> 4
-    hi = p.astype(jnp.int8) >> 4
-    return jnp.stack([lo, hi], axis=1).reshape(2 * p.shape[0], p.shape[1])
+    arithmetic on 32-bit lanes (Mosaic has no 8-bit shifts): the low nibble
+    sign-extends via ``(p << 28) >> 28``, the high nibble via
+    ``(p << 24) >> 28`` (the arithmetic right shift carries the sign).  The
+    stack-reshape interleaves along the sublane axis only — the 128-lane
+    layout is untouched."""
+    w = p.astype(jnp.int32)
+    lo = (w << 28) >> 28
+    hi = (w << 24) >> 28
+    return jnp.stack([lo, hi], axis=1).reshape(2 * p.shape[0], p.shape[1]).astype(jnp.int8)
 
 
 def _qmatmul_packed_kernel(x_ref, wp_ref, b_ref, qs_ref, qsh_ref, o_ref, acc_ref, *, relu, two_mul, out_dtype):

@@ -41,6 +41,46 @@ def qmatmul_ref(
     return jnp.clip(r, info.min, info.max).astype(out_dtype)
 
 
+def _split(x: jax.Array):
+    """Veltkamp split: ``x == hi + lo`` exactly, each half with at most 12
+    significant bits, so a product of two halves is exact in f32."""
+    c = x * jnp.float32(4097.0)
+    hi = c - (c - x)
+    return hi, x - hi
+
+
+def _nearest(a: jax.Array, b: jax.Array, q: jax.Array) -> jax.Array:
+    """Move the positive quotient ``q`` of ``a / b`` one ulp toward the
+    round-to-nearest-even result where it is not already that result.  The
+    residual ``a - q·b`` is exact (Dekker's product, no FMA), and so are the
+    half-gaps it is compared with (an ulp times ``b``)."""
+    bits = jax.lax.bitcast_convert_type(q, jnp.int32)
+    up = jax.lax.bitcast_convert_type(bits + 1, jnp.float32)
+    dn = jax.lax.bitcast_convert_type(bits - 1, jnp.float32)
+    p = q * b
+    qh, ql = _split(q)
+    bh, bl = _split(b)
+    r2 = ((a - p) - (((qh * bh - p) + qh * bl + ql * bh) + ql * bl)) * 2.0
+    odd = (bits & 1) == 1
+    gap_up, gap_dn = (up - q) * b, (q - dn) * b
+    go_up = (r2 > gap_up) | ((r2 == gap_up) & odd)
+    go_dn = (-r2 > gap_dn) | ((-r2 == gap_dn) & odd)
+    return jnp.where(go_up, up, jnp.where(go_dn, dn, q))
+
+
+def div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
+    """IEEE ``a / b`` (round to nearest even) for integer-valued f32
+    ``0 <= a`` and ``1 <= b < 2**24``, on any backend.
+
+    TPU f32 division, in XLA and in Mosaic alike, is not correctly rounded
+    (on a TPU v5e about a third of the quotients of such integers differ
+    from IEEE in the last bit), so the hardware quotient is corrected twice against the exact
+    residual: enough for one within two ulps.  Where division is IEEE the
+    corrections change nothing."""
+    q = _nearest(a, b, _nearest(a, b, a / b))
+    return jnp.where(a == 0, jnp.float32(0.0), q)
+
+
 def qact_lut_ref(x_q: jax.Array, lut: jax.Array) -> jax.Array:
     """256-entry LUT gather oracle."""
     return jnp.take(lut, x_q.astype(jnp.int32) + 128)
@@ -65,7 +105,8 @@ def qattention_ref(
     ``reference runtime == ref == kernel(interpret=True)`` bit-for-bit.
 
     Every step is either integer arithmetic or an IEEE-exact f32 elementwise
-    op, so the chain is deterministic across numpy / XLA / Pallas:
+    op (the Div through :func:`div_rn`), so the chain is deterministic across
+    numpy / XLA / Pallas and CPU / TPU:
 
         MatMulInteger(Q, K^T) → ×qk_scale → additive {0,-big} mask →
         ReduceMax/Sub (running-max-free softmax shift) → QuantizeLinear(ls) →
@@ -81,7 +122,7 @@ def qattention_ref(
     d_q = jnp.clip(jnp.rint(d / lut_scale), -128, 127).astype(jnp.int32)
     w = jnp.take(lut, d_q + 128)  # uint8 weights; masked positions hit lut[0] == 0
     den = jnp.sum(w.astype(jnp.int32), axis=-1, keepdims=True)
-    p = w.astype(jnp.float32) / den.astype(jnp.float32)
+    p = div_rn(w.astype(jnp.float32), den.astype(jnp.float32))
     p_q = jnp.clip(jnp.rint(p * p_scale), -128, 127).astype(jnp.int32)
     ctx = jnp.matmul(p_q, v_q.astype(jnp.int32))
     f = ctx.astype(jnp.float32) * rescale

@@ -15,6 +15,7 @@ import numpy as np
 from ..configs import get_config
 from ..models import model as M
 from ..serving.engine import EngineConfig, Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def serve_demo(
@@ -75,6 +76,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--top-k", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     serve_demo(
         args.arch, requests=args.requests, prompt_len=args.prompt_len,
         new_tokens=args.new_tokens, slots=args.slots, int8_kv=args.int8_kv,

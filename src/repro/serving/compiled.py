@@ -52,7 +52,6 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-import jax
 import numpy as np
 
 from ..backend.autotune import TuneJob
@@ -466,7 +465,7 @@ class CompiledModelServer:
             # cache_key: graph-qualified when the cache is fleet-shared, the
             # plain bindings key otherwise — must match what step() looks up
             self.cm.plan_cache.put(
-                self.cm.cache_key(job.bindings), (plan, jax.jit(plan.execute))
+                self.cm.cache_key(job.bindings), (plan, plan.jit())
             )
             self._count("tuned_swaps")
             self.registry.counter("autotune.swaps").inc()

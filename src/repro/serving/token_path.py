@@ -474,21 +474,22 @@ class CompiledTokenPath:
             logits, nxt = self.decode(tokens, onehot, mask, cache)
             return logits[:, 0, :], nxt
         plan, _ = cm.specialized({"N": n, "S": s})  # per-step cell accounting
-        fn = self._step_fns.get((n, s))
-        if fn is None:
+        entry = self._step_fns.get((n, s))
+        if entry is None:
             logits_name, specs = self._logits_decode, self.state_specs
 
-            def step(toks, pos, cache):
+            def step(params, toks, pos, cache):
                 onehot = (jnp.arange(s)[None, :, None] == pos[:, None, None]).astype(jnp.int8)
                 mask = (jnp.arange(s)[None, None, :] <= pos[:, None, None]).astype(jnp.float32)
                 feeds = {"tokens": toks, "onehot": onehot, "mask": mask}
                 feeds.update(cache)
-                outs = plan.execute(feeds)
+                outs = plan.execute(feeds, params)
                 return outs[logits_name][:, 0, :], {sp.input: outs[sp.output] for sp in specs}
 
-            fn = self._step_fns[(n, s)] = jax.jit(step)
+            entry = self._step_fns[(n, s)] = (jax.jit(step), plan.params())
+        fn, params = entry
         logits, nxt = fn(
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache
+            params, jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache
         )
         return np.asarray(logits), nxt
 

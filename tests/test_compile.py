@@ -227,5 +227,10 @@ class TestLutKernel:
         for backend in ("ref", "interpret"):
             got = kops.quantized_activation(jnp.asarray(x), lut, backend=backend)
             np.testing.assert_array_equal(np.asarray(got), expect)
-        got = kops.quantized_activation(jnp.asarray(x), lut, backend="interpret", one_hot=True)
-        np.testing.assert_array_equal(np.asarray(got), expect)
+        # a uint8 table (values above 127) over a shape off the kernel's
+        # blocks: padded, looked up exactly, sliced back
+        lut_u8 = build_lut(lambda v: 1.0 / (1.0 + np.exp(-v)), 8.0 / 127.0, 1.0 / 255.0, "uint8")
+        assert lut_u8.max() > 127
+        x = np.random.default_rng(1).integers(-128, 128, (3, 37, 200)).astype(np.int8)
+        got = kops.quantized_activation(jnp.asarray(x), lut_u8, backend="interpret")
+        np.testing.assert_array_equal(np.asarray(got), lut_u8[x.astype(np.int32) + 128])

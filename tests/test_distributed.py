@@ -70,6 +70,7 @@ class TestShardedTrainStep:
             from repro.configs.base import ShapeConfig
             from repro.distributed.sharding import use_mesh
             from repro.launch import steps as S, specs as SP
+            from repro.launch.mesh import make_test_mesh
             from repro.models import model as M
             from repro.optim import adamw
             from repro.data.pipeline import Pipeline, DataConfig
@@ -83,7 +84,7 @@ class TestShardedTrainStep:
 
             p1, o1, m1 = jax.jit(step)(params, opt, batch)   # single device
 
-            mesh = jax.make_mesh((2, 2), ("data", "model"))
+            mesh = make_test_mesh((2, 2), ("data", "model"))
             with use_mesh(mesh):
                 p_sh = SP.params_shardings(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)), mesh)
                 o_sh = {"m": p_sh, "v": p_sh, "step": None}
@@ -111,11 +112,11 @@ class TestGradCompression:
         accumulated bias to ~0 over repeated steps on a persistent gradient."""
         code = """
             import jax, jax.numpy as jnp, numpy as np
-            from jax.sharding import Mesh, PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
+            from jax.sharding import PartitionSpec as P
+            from repro.launch.mesh import make_test_mesh
             from repro.optim.grad_compress import compressed_cross_pod_mean, init_residuals
 
-            mesh = jax.make_mesh((8,), ("pod",))
+            mesh = make_test_mesh((8,), ("pod",))
             g_global = jnp.asarray(np.random.default_rng(0).normal(size=(8, 64)).astype(np.float32))
             true_mean = g_global.mean(axis=0)
 
@@ -124,7 +125,7 @@ class TestGradCompression:
                 def body(g_l, r_l):
                     gm, r2 = compressed_cross_pod_mean({"w": g_l[0]}, {"w": r_l[0]}, axis="pod")
                     return gm["w"][None], r2["w"][None]
-                return shard_map(body, mesh=mesh,
+                return jax.shard_map(body, mesh=mesh,
                                  in_specs=(P("pod"), P("pod")),
                                  out_specs=(P("pod"), P("pod")))(g, res)
 

@@ -395,3 +395,29 @@ class TestAutotuneAttention:
             for _, rec in ev.tiles
         ]
         assert any("[tuned]" in r for r in recs)
+
+
+class TestExactDivision:
+    """``kernels.ref.div_rn``: the attention softmax's Div, IEEE on any
+    backend.  A TPU's divide is off by an ulp for many such quotients; the
+    correction is pinned here against quotients moved off by up to two."""
+
+    @pytest.mark.parametrize("off", [0, 1, -1, 2, -2])
+    def test_corrects_an_inexact_quotient(self, off):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.kernels import ref
+
+        rng = np.random.default_rng(off + 7)
+        b = rng.integers(1, 255 * 1024, 200_000).astype(np.float32)
+        b[:50_000] = rng.integers(1, 600, 50_000)
+        a = np.minimum(np.floor(rng.random(b.size) * (b + 1)), b).astype(np.float32)
+        a[:100] = 0
+        ieee = a / b
+        bits = jax.lax.bitcast_convert_type(jnp.asarray(ieee), jnp.int32) + off
+        q = jnp.where(jnp.asarray(a) == 0, 0.0, jax.lax.bitcast_convert_type(bits, jnp.float32))
+        fix = jax.jit(lambda a, b, q: ref._nearest(a, b, ref._nearest(a, b, q)))
+        got = np.asarray(fix(jnp.asarray(a), jnp.asarray(b), q))
+        np.testing.assert_array_equal(got[a > 0], ieee[a > 0])
+        np.testing.assert_array_equal(np.asarray(ref.div_rn(jnp.asarray(a), jnp.asarray(b))), ieee)
