@@ -1,0 +1,7 @@
+"""``mfu`` in the cells judged by their tails (open loop below the
+knee), where the device's work moves ``itl_p99_ms``: the same reading as
+``bench/metrics/mfu.py``."""
+
+
+def read(run):
+    return run.cell.module("metrics", "mfu").read(run)

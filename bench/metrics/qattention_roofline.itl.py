@@ -1,0 +1,7 @@
+"""``qattention_roofline`` in the cells judged by their tails (open loop below the
+knee), where the device's work moves ``itl_p99_ms``: the same reading as
+``bench/metrics/qattention_roofline.py``."""
+
+
+def read(run):
+    return run.cell.module("metrics", "qattention_roofline").read(run)

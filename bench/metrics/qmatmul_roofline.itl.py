@@ -1,0 +1,7 @@
+"""``qmatmul_roofline`` in the cells judged by their tails (open loop below the
+knee), where the device's work moves ``itl_p99_ms``: the same reading as
+``bench/metrics/qmatmul_roofline.py``."""
+
+
+def read(run):
+    return run.cell.module("metrics", "qmatmul_roofline").read(run)
