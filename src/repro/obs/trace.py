@@ -13,6 +13,14 @@ exports two ways:
 * :meth:`Tracer.render_tree` — a human-readable nested tree with durations
   and attributes, for terminals and bug reports.
 
+Every span gets an id, and every record names its ``parent``: the span that
+was open on its thread when it began.  A tracer given a profiler hook
+(``Tracer(annotate=jax.profiler.TraceAnnotation)``) also enters an
+annotation of the span's name for as long as the span is open, so each span
+lands in the profiler's trace on the profiler's clock, beside the device
+operations it caused.  The span's attributes stay in the tracer's own
+record, so the annotation's name is exactly the span's name.
+
 Install/uninstall discipline
 ============================
 
@@ -34,7 +42,7 @@ import itertools
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 #: True iff a tracer is installed.  Hot paths guard on this before building
 #: span attribute dicts; everything else just calls :func:`span`.
@@ -55,6 +63,9 @@ class SpanRecord:
     depth  nesting depth within its thread at record time (spans only)
     aid    async-link id ("async_b"/"async_e" only) — entries sharing an aid
            form one logical flow (e.g. one serving request)
+    sid    this span's id, unique within the tracer (spans only)
+    parent the ``sid`` of the span open on the same thread when this entry
+           began, ``None`` at the top
     """
 
     name: str
@@ -65,18 +76,23 @@ class SpanRecord:
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     kind: str = "span"
     aid: Optional[int] = None
+    sid: Optional[int] = None
+    parent: Optional[int] = None
 
 
 class _ActiveSpan:
     """Context manager for one open span; finishing records it."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0")
+    __slots__ = ("tracer", "name", "attrs", "t0", "sid", "parent", "note")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
+        self.sid = 0
+        self.parent: Optional[int] = None
+        self.note = None  # the profiler annotation, while the span is open
 
     def set(self, **attrs: Any) -> "_ActiveSpan":
         """Attach attributes discovered mid-span (e.g. chosen tiles)."""
@@ -84,14 +100,21 @@ class _ActiveSpan:
         return self
 
     def __enter__(self) -> "_ActiveSpan":
-        self.t0 = time.perf_counter()
         self.tracer._enter(self)
+        if self.tracer.annotate is not None:
+            self.note = self.tracer.annotate(self.name)
+            self.note.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        if self.note is not None:
+            self.note.__exit__(exc_type, exc, tb)
+            self.note = None
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
-        self.tracer._exit(self, time.perf_counter())
+        self.tracer._exit(self, t1)
         return False
 
 
@@ -119,11 +142,18 @@ class Tracer:
 
     Per-thread nesting is tracked in a ``threading.local`` stack; finished
     records append to one list under a lock (recording is the only
-    synchronized operation, and it is O(1))."""
+    synchronized operation, and it is O(1)).
 
-    def __init__(self, trace_id: Optional[str] = None) -> None:
+    ``annotate``, where given, is the profiler hook: called with a span's
+    name as the span opens, it returns a context manager that the span
+    enters then and leaves as it closes (``jax.profiler.TraceAnnotation``)."""
+
+    def __init__(self, trace_id: Optional[str] = None, *,
+                 annotate: Optional[Callable[[str], Any]] = None) -> None:
         self.trace_id = trace_id or f"trace-{next(_IDS)}-{int(time.time())}"
+        self.annotate = annotate
         self.epoch = time.perf_counter()
+        self._sids = itertools.count(1)
         self._lock = threading.Lock()
         self._records: List[SpanRecord] = []
         self._local = threading.local()
@@ -141,7 +171,7 @@ class Tracer:
                 SpanRecord(
                     name=name, ts=now - self.epoch, dur=0.0,
                     tid=self._tid(), depth=self._depth(), attrs=attrs,
-                    kind="instant",
+                    kind="instant", parent=self._parent(),
                 )
             )
 
@@ -154,7 +184,7 @@ class Tracer:
             self._records.append(
                 SpanRecord(
                     name=name, ts=now - self.epoch, dur=0.0, tid=self._tid(),
-                    attrs=attrs, kind="async_b", aid=aid,
+                    attrs=attrs, kind="async_b", aid=aid, parent=self._parent(),
                 )
             )
 
@@ -164,7 +194,7 @@ class Tracer:
             self._records.append(
                 SpanRecord(
                     name=name, ts=now - self.epoch, dur=0.0, tid=self._tid(),
-                    attrs=attrs, kind="async_e", aid=aid,
+                    attrs=attrs, kind="async_e", aid=aid, parent=self._parent(),
                 )
             )
 
@@ -177,6 +207,10 @@ class Tracer:
     def _depth(self) -> int:
         return len(self._stack())
 
+    def _parent(self) -> Optional[int]:
+        stack = self._stack()
+        return stack[-1].sid if stack else None
+
     def _tid(self) -> int:
         ident = threading.get_ident()
         tid = self._tids.get(ident)
@@ -185,6 +219,8 @@ class Tracer:
         return tid
 
     def _enter(self, span: _ActiveSpan) -> None:
+        span.sid = next(self._sids)
+        span.parent = self._parent()
         self._stack().append(span)
 
     def _exit(self, span: _ActiveSpan, t1: float) -> None:
@@ -200,7 +236,7 @@ class Tracer:
                 SpanRecord(
                     name=span.name, ts=span.t0 - self.epoch,
                     dur=t1 - span.t0, tid=self._tid(), depth=len(stack),
-                    attrs=span.attrs, kind="span",
+                    attrs=span.attrs, kind="span", sid=span.sid, parent=span.parent,
                 )
             )
 

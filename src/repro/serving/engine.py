@@ -52,6 +52,7 @@ class Request:
     generated: Optional[List[int]] = None
     done: bool = False
     t_submit: float = 0.0
+    t_admit: Optional[float] = None  # left the queue
     t_first: Optional[float] = None
     t_done: Optional[float] = None
 
@@ -249,6 +250,7 @@ class ServeEngine:
         self._prefill_cache: Optional[PlanCache] = getattr(adapter, "prefill_cache", None)
         if self._prefill_cache is not None:
             self._prefill_cache.attach_metrics(self.registry)
+        self._queue_wait = self.registry.histogram("engine.queue_wait_ms")
         self.metrics = {
             "decode_steps": 0,
             "prefills": 0,
@@ -311,53 +313,71 @@ class ServeEngine:
         self.metrics["prefill_cache_hit_rate"] = stats["hit_rate"]
 
     def _admit(self) -> None:
-        for slot in range(self.ecfg.slots):
-            # a request whose budget is exhausted by the prefill token never
-            # occupies the slot, so keep admitting until it is actually filled
-            while not self.slot_live[slot] and self.queue:
-                req = self.queue.popleft()
-                plen = len(req.prompt)
-                bucket = bucket_multiple(plen, self.ecfg.prefill_bucket)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :plen] = req.prompt
-                # prefill writes [0, bucket); only [0, plen) is meaningful — the
-                # causal mask means padding beyond plen is never attended by
-                # positions < plen, and decode continues exactly at plen.
-                with _trace.span("engine.prefill", uid=req.uid, plen=plen, bucket=bucket):
-                    first_logits, pcache = self.adapter.prefill(
-                        padded, plen, self.ecfg.max_len
-                    )
-                self._sync_cache_metrics()
-                tok = self._select(first_logits)
-                req.generated.append(tok)
-                req.t_first = time.monotonic()
-                self._count("prefills")
-                if req.max_new_tokens <= 1:
-                    # the prefill token already spent the whole budget: done at
-                    # admit — decoding the slot once more would emit a second
-                    # token and violate max_new_tokens
-                    req.done = True
-                    req.t_done = req.t_first
-                    self._count("completed")
-                    continue
-                self.cache = self.adapter.scatter(self.cache, slot, pcache)
-                self.active[slot] = req
-                self.slot_pos[slot] = plen
-                self.slot_live[slot] = True
-                self.slot_budget[slot] = req.max_new_tokens - 1
+        with _trace.span("engine.admit"):
+            for slot in range(self.ecfg.slots):
+                # a request whose budget is exhausted by the prefill token never
+                # occupies the slot, so keep admitting until it is actually filled
+                while not self.slot_live[slot] and self.queue:
+                    self._admit_one(slot, self.queue.popleft())
+
+    def _admit_one(self, slot: int, req: Request) -> None:
+        req.t_admit = time.monotonic()
+        self._queue_wait.observe((req.t_admit - req.t_submit) * 1e3)
+        plen = len(req.prompt)
+        bucket = bucket_multiple(plen, self.ecfg.prefill_bucket)
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :plen] = req.prompt
+        # prefill writes [0, bucket); only [0, plen) is meaningful — the
+        # causal mask means padding beyond plen is never attended by
+        # positions < plen, and decode continues exactly at plen.
+        with _trace.span("engine.prefill") as sp:
+            if _trace.enabled:
+                sp.set(uid=req.uid, plen=plen, bucket=bucket)
+            first_logits, pcache = self.adapter.prefill(padded, plen, self.ecfg.max_len)
+        self._sync_cache_metrics()
+        tok = self._select(first_logits)
+        req.generated.append(tok)
+        req.t_first = time.monotonic()
+        self._count("prefills")
+        if req.max_new_tokens <= 1:
+            # the prefill token already spent the whole budget: done at
+            # admit — decoding the slot once more would emit a second
+            # token and violate max_new_tokens
+            req.done = True
+            req.t_done = req.t_first
+            self._count("completed")
+            return
+        with _trace.span("engine.scatter") as sp:
+            if _trace.enabled:
+                sp.set(uid=req.uid, slot=slot)
+            self.cache = self.adapter.scatter(self.cache, slot, pcache)
+        self.active[slot] = req
+        self.slot_pos[slot] = plen
+        self.slot_live[slot] = True
+        self.slot_budget[slot] = req.max_new_tokens - 1
 
     # -- main loop --------------------------------------------------------------
     def step(self) -> None:
         """One engine cycle: admit + one batched decode step."""
-        self._admit()
-        if not self.slot_live.any():
-            return
+        with _trace.span("engine.step"):
+            self._admit()
+            if self.slot_live.any():
+                self._decode()
+
+    def _decode(self) -> None:
         toks = np.zeros((self.ecfg.slots, 1), np.int32)
         for slot, req in self.active.items():
             toks[slot, 0] = req.generated[-1]
-        with _trace.span("engine.decode", live=int(self.slot_live.sum())):
+        with _trace.span("engine.decode") as sp:
+            if _trace.enabled:
+                sp.set(live=int(self.slot_live.sum()))
             logits, self.cache = self.adapter.decode(toks, self.slot_pos, self.cache)
         self._count("decode_steps")
+        with _trace.span("engine.select"):
+            self._advance(logits)
+
+    def _advance(self, logits) -> None:
+        """Choose each live slot's next token and retire finished requests."""
         if self.ecfg.greedy:
             # argmax on device: transfers `slots` ints, not slots×vocab floats
             nxt = np.asarray(jnp.argmax(logits, axis=-1))

@@ -56,6 +56,7 @@ from ..core.patterns import (
 )
 from ..core.quant import QuantizedLinearParams, quantize_linear_layer
 from ..kernels import ref as _ref
+from ..obs import trace as _trace
 
 __all__ = [
     "TokenPathConfig",
@@ -488,10 +489,17 @@ class CompiledTokenPath:
 
             entry = self._step_fns[(n, s)] = (jax.jit(step), plan.params())
         fn, params = entry
-        logits, nxt = fn(
-            params, jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache
-        )
-        return np.asarray(logits), nxt
+        if _trace.enabled and any(isinstance(v, np.ndarray) for v in cache.values()):
+            # traced only: the host cache goes to the device here, not inside
+            # the jitted call's dispatch, so that its own span can time it
+            with _trace.span("tokenpath.decode.put"):
+                cache = jax.block_until_ready(jax.device_put(cache))
+        with _trace.span("tokenpath.decode.dispatch"):
+            logits, nxt = fn(
+                params, jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache
+            )
+        with _trace.span("tokenpath.decode.fetch"):
+            return np.asarray(logits), nxt
 
     def init_cache(self, n: int, s: int) -> Dict[str, np.ndarray]:
         D = self.cfg.d_model
@@ -529,18 +537,23 @@ class CompiledTokenAdapter:
 
     def prefill(self, padded: np.ndarray, plen: int, max_len: int):
         bucket = padded.shape[1]
-        logits, cache = self.tp.prefill(padded, self._causal_mask(1, bucket))
+        with _trace.span("tokenpath.prefill.mask"):
+            mask = self._causal_mask(1, bucket)
+        logits, cache = self.tp.prefill(padded, mask)
         return logits[0, plen - 1], cache
 
     def scatter(self, cache, slot: int, pcache):
         # cache values may be device arrays (the decode fast path keeps them
-        # there between steps); np.array materializes either kind
+        # there between steps); np.asarray materializes either kind
         out = {}
         for name, buf in cache.items():
-            rows = np.asarray(pcache[name])
-            dst = np.array(buf, copy=True)
-            n = min(rows.shape[1], dst.shape[1])
-            dst[slot, :n] = rows[0, :n]
+            with _trace.span("tokenpath.scatter.fetch"):
+                host = np.asarray(buf)
+            with _trace.span("tokenpath.scatter.write"):
+                rows = np.asarray(pcache[name])
+                dst = np.array(host, copy=True)
+                n = min(rows.shape[1], dst.shape[1])
+                dst[slot, :n] = rows[0, :n]
             # rows ≥ prompt bucket keep their zeros: masked until the decode
             # onehot overwrites them position by position
             out[name] = dst

@@ -123,6 +123,84 @@ class TestTracer:
         assert all(r.depth == 0 for r in tracer.spans("worker"))
 
 
+class _Hook:
+    """A profiler hook that logs when each annotation is entered and left."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        hook = self
+
+        class Annotation:
+            def __enter__(self):
+                hook.log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                hook.log.append(("exit", name))
+
+        return Annotation()
+
+
+class TestProfilerHook:
+    def test_annotations_enter_and_leave_in_nesting_order(self):
+        hook = _Hook()
+        tracer = obs_trace.install(obs_trace.Tracer(annotate=hook))
+        try:
+            with obs_trace.span("outer", uid=3):
+                with obs_trace.span("inner"):
+                    pass
+                with obs_trace.span("second"):
+                    pass
+        finally:
+            obs_trace.uninstall()
+        assert hook.log == [("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+                            ("enter", "second"), ("exit", "second"), ("exit", "outer")]
+        # the attributes stay in the tracer's record, not in the annotation
+        outer, = tracer.spans("outer")
+        assert outer.attrs == {"uid": 3}
+
+    def test_an_exception_still_leaves_the_annotation(self):
+        hook = _Hook()
+        obs_trace.install(obs_trace.Tracer(annotate=hook))
+        try:
+            with pytest.raises(RuntimeError):
+                with obs_trace.span("boom"):
+                    raise RuntimeError("x")
+        finally:
+            obs_trace.uninstall()
+        assert hook.log == [("enter", "boom"), ("exit", "boom")]
+
+    def test_parent_links_each_record_to_the_open_span(self, tracer):
+        with obs_trace.span("step"):
+            with obs_trace.span("decode"):
+                obs_trace.event("cache.plan.hit")
+            with obs_trace.span("select"):
+                pass
+        with obs_trace.span("next"):
+            pass
+        by_name = {r.name: r for r in tracer.records}
+        step = by_name["step"]
+        assert step.parent is None and by_name["next"].parent is None
+        assert by_name["decode"].parent == step.sid
+        assert by_name["select"].parent == step.sid
+        assert by_name["cache.plan.hit"].parent == by_name["decode"].sid
+        sids = [r.sid for r in tracer.spans()]
+        assert None not in sids and len(set(sids)) == len(sids)
+
+    def test_threads_keep_their_own_parents(self, tracer):
+        def work():
+            with obs_trace.span("worker"):
+                pass
+
+        with obs_trace.span("main"):
+            t = threading.Thread(target=work)
+            t.start()
+            t.join()
+        worker, = tracer.spans("worker")
+        assert worker.parent is None
+
+
 class TestNoTracer:
     def test_span_is_shared_noop_singleton(self):
         assert obs_trace.current() is None and not obs_trace.enabled
@@ -133,6 +211,42 @@ class TestNoTracer:
         obs_trace.event("x")  # no-ops, no error
         obs_trace.async_begin("x", 1)
         obs_trace.async_end("x", 1)
+
+    def test_token_path_records_nothing_and_calls_no_hook(self, monkeypatch):
+        """Served with no tracer installed, the token path opens no span,
+        calls no profiler hook, and neither puts to nor waits on the device
+        beyond what its jitted calls do."""
+        import jax
+
+        from repro.serving.engine import EngineConfig, Request, ServeEngine
+        from repro.serving.token_path import CompiledTokenAdapter, CompiledTokenPath, TokenPathConfig
+
+        calls = []
+        for name in ("block_until_ready", "device_put"):
+            real = getattr(jax, name)
+            monkeypatch.setattr(jax, name, lambda *a, _real=real, _name=name, **k: (
+                calls.append(_name), _real(*a, **k))[1])
+        tp = CompiledTokenPath(TokenPathConfig(), backend="ref", seed=1, s_granularity=8)
+
+        def serve():
+            eng = ServeEngine(ecfg=EngineConfig(slots=2, max_len=16, prefill_bucket=8),
+                              adapter=CompiledTokenAdapter(tp))
+            for i in range(3):
+                eng.submit(Request(uid=i, prompt=np.arange(1, 4 + i, dtype=np.int32), max_new_tokens=3))
+            eng.run_until_drained()
+
+        hook = _Hook()
+        tracer = obs_trace.Tracer(annotate=hook)
+        serve()  # no tracer installed
+        assert obs_trace.current() is None
+        assert hook.log == [] and tracer.records == [] and calls == []
+        # the same serving, traced, does call the hook and put the cache
+        obs_trace.install(tracer)
+        try:
+            serve()
+        finally:
+            obs_trace.uninstall()
+        assert hook.log and tracer.spans("engine.step") and "device_put" in calls
 
     def test_uninstrumented_overhead_smoke(self):
         """The no-tracer fast path is a global read + a shared singleton;

@@ -27,6 +27,7 @@ from repro.core import pqir
 from repro.core.compile import compile_model
 from repro.core.patterns import build_exp_lut, emit_qattention
 from repro.core.runtime import ReferenceRuntime
+from repro.obs import trace as obs_trace
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.serving.token_path import (
     CompiledTokenAdapter,
@@ -316,6 +317,106 @@ class TestSharedCacheServing:
             toks.append(int(np.asarray(jl)[0, 0].argmax()))
             pos += 1
         assert req.generated == toks
+
+
+#: Each token-path span and the span it opens inside.
+SPAN_PARENTS = {
+    "engine.step": None,
+    "engine.admit": "engine.step",
+    "engine.prefill": "engine.admit",
+    "tokenpath.prefill.mask": "engine.prefill",
+    "run.pad": "engine.prefill",
+    "run.execute": "engine.prefill",
+    "run.slice": "engine.prefill",
+    "engine.scatter": "engine.admit",
+    "tokenpath.scatter.fetch": "engine.scatter",
+    "tokenpath.scatter.write": "engine.scatter",
+    "engine.decode": "engine.step",
+    "tokenpath.decode.put": "engine.decode",
+    "tokenpath.decode.dispatch": "engine.decode",
+    "tokenpath.decode.fetch": "engine.decode",
+    "engine.select": "engine.step",
+}
+
+
+class TestTokenPathSpans:
+    """The serving loop's spans over the compiled token path, traced."""
+
+    @staticmethod
+    def _serve(tracer=None, n=4):
+        """Staggered requests over two slots, served cycle by cycle, with
+        ``tracer`` installed where given; returns the engine and its requests."""
+        tp = _tp("ref")
+        eng = ServeEngine(
+            ecfg=EngineConfig(slots=2, max_len=16, prefill_bucket=8),
+            adapter=CompiledTokenAdapter(tp),
+        )
+        rng = np.random.default_rng(11)
+        reqs = [
+            Request(uid=i, prompt=_tokens(rng, 1, int(rng.integers(2, 8)))[0], max_new_tokens=3 + i)
+            for i in range(n)
+        ]
+        if tracer is not None:
+            obs_trace.install(tracer)
+        try:
+            for r in reqs:
+                eng.submit(r)
+            for _ in range(64):
+                if not eng.queue and not eng.active:
+                    break
+                eng.step()
+        finally:
+            if tracer is not None:
+                obs_trace.uninstall()
+        return eng, reqs
+
+    def test_spans_nest_as_documented(self):
+        tracer = obs_trace.Tracer()
+        eng, reqs = self._serve(tracer)
+        by_sid = {r.sid: r for r in tracer.spans()}
+        # first visits of a cell also open backend.specialize spans
+        spans = [r for r in by_sid.values() if r.name in SPAN_PARENTS]
+        assert {r.name for r in spans} == set(SPAN_PARENTS)
+        for r in spans:
+            parent = by_sid[r.parent].name if r.parent is not None else None
+            assert parent == SPAN_PARENTS[r.name], r
+        assert len(tracer.spans("engine.step")) == eng.metrics["decode_steps"]
+        assert len(tracer.spans("engine.prefill")) == eng.metrics["prefills"] == len(reqs)
+        # request spans carry their uid
+        assert sorted(s.attrs["uid"] for s in tracer.spans("engine.prefill")) == [r.uid for r in reqs]
+        assert {s.attrs["uid"] for s in tracer.spans("engine.scatter")} == {r.uid for r in reqs}
+        # one fetch and one write per cache array per admission
+        n_arrays = len(eng.cache)
+        assert len(tracer.spans("tokenpath.scatter.fetch")) == n_arrays * len(reqs)
+
+    def test_the_cache_is_put_only_on_the_decode_after_an_admission(self):
+        tracer = obs_trace.Tracer()
+        self._serve(tracer)
+        by_sid = {r.sid: r for r in tracer.spans()}
+        steps = {sid: set() for sid, r in by_sid.items() if r.name == "engine.step"}
+        for r in by_sid.values():
+            top = r
+            while top.parent is not None:
+                top = by_sid[top.parent]
+            steps[top.sid].add(r.name)
+        admitted = [("engine.scatter" in names) for names in steps.values()]
+        put = [("tokenpath.decode.put" in names) for names in steps.values()]
+        assert put == admitted
+        assert any(put) and not all(put)
+
+    def test_tokens_equal_with_and_without_the_tracer(self):
+        _, plain = self._serve()
+        _, traced = self._serve(obs_trace.Tracer())
+        assert [r.generated for r in plain] == [r.generated for r in traced]
+        assert all(r.done for r in plain)
+
+    def test_queue_wait_is_stamped_at_admission(self):
+        eng, reqs = self._serve()
+        for r in reqs:
+            assert r.t_submit <= r.t_admit <= r.t_first
+        wait = eng.registry.histogram("engine.queue_wait_ms")
+        assert wait.count == len(reqs)
+        assert wait.max == pytest.approx(max(1e3 * (r.t_admit - r.t_submit) for r in reqs))
 
 
 class TestAttentionLane:
