@@ -57,6 +57,7 @@ from ..core.patterns import (
 from ..core.quant import QuantizedLinearParams, quantize_linear_layer
 from ..kernels import ref as _ref
 from ..obs import trace as _trace
+from ..obs.metrics import default_registry
 
 __all__ = [
     "TokenPathConfig",
@@ -463,10 +464,14 @@ class CompiledTokenPath:
         hits thereafter.  Falls back to :meth:`decode` when the extents are
         not bucket-aligned (then padding/slicing is required and the slow
         path is the correct one).  Returns (logits (N, V) ndarray, next
-        cache of device arrays)."""
+        cache of device arrays).  A cache that comes in on the host counts
+        one ``tokenpath.cache.host_trips``."""
         n = int(np.shape(tokens)[0])
         s = int(np.shape(next(iter(cache.values())))[1])
         cm = self.decode_cm
+        on_host = any(isinstance(v, np.ndarray) for v in cache.values())
+        if on_host:
+            _count_host_trip()
         if cm.bucket_for("N", n) != n or cm.bucket_for("S", s) != s:
             pos = np.asarray(pos, np.int64)
             onehot = np.zeros((n, s, 1), np.int8)
@@ -489,7 +494,7 @@ class CompiledTokenPath:
 
             entry = self._step_fns[(n, s)] = (jax.jit(step), plan.params())
         fn, params = entry
-        if _trace.enabled and any(isinstance(v, np.ndarray) for v in cache.values()):
+        if _trace.enabled and on_host:
             # traced only: the host cache goes to the device here, not inside
             # the jitted call's dispatch, so that its own span can time it
             with _trace.span("tokenpath.decode.put"):
@@ -509,6 +514,20 @@ class CompiledTokenPath:
         return self.plan_cache.stats
 
 
+def _count_host_trip() -> None:
+    """One whole KV cache moved between host and device by the token path."""
+    default_registry().counter("tokenpath.cache.host_trips").inc()
+
+
+def _write_rows(cache, rows, slot):
+    """Each cache array with ``rows[name]`` ``(1, b, D)`` written over
+    positions ``[0, b)`` of ``slot``; every other row is left as it was."""
+    return {
+        name: jax.lax.dynamic_update_slice(buf, rows[name], (slot, 0, 0))
+        for name, buf in cache.items()
+    }
+
+
 class CompiledTokenAdapter:
     """ServeEngine adapter for the compiled token path.
 
@@ -524,10 +543,14 @@ class CompiledTokenAdapter:
         # no per-bucket jitted-fn cache here — plan specialization IS the
         # per-bucket discipline, surfaced via tp.cache_stats()
         self.prefill_cache = None
+        # the slot is traced: one program per prompt bucket, not per slot;
+        # the cache is donated, so the rows are written in place
+        self._write_rows = jax.jit(_write_rows, donate_argnums=0)
 
     def init_cache(self, slots: int, max_len: int):
+        """The zero cache, on the device: admission and decode keep it there."""
         self.max_len = max_len
-        return self.tp.init_cache(slots, max_len)
+        return jax.device_put(self.tp.init_cache(slots, max_len))
 
     @staticmethod
     def _causal_mask(n: int, s: int) -> np.ndarray:
@@ -543,21 +566,22 @@ class CompiledTokenAdapter:
         return logits[0, plen - 1], cache
 
     def scatter(self, cache, slot: int, pcache):
-        # cache values may be device arrays (the decode fast path keeps them
-        # there between steps); np.asarray materializes either kind
-        out = {}
-        for name, buf in cache.items():
-            with _trace.span("tokenpath.scatter.fetch"):
-                host = np.asarray(buf)
-            with _trace.span("tokenpath.scatter.write"):
-                rows = np.asarray(pcache[name])
-                dst = np.array(host, copy=True)
-                n = min(rows.shape[1], dst.shape[1])
-                dst[slot, :n] = rows[0, :n]
-            # rows ≥ prompt bucket keep their zeros: masked until the decode
-            # onehot overwrites them position by position
-            out[name] = dst
-        return out
+        """Write one prompt's K/V rows ``(1, bucket, D)`` into ``slot`` of the
+        device cache, in place (the cache passed in is donated).  Positions at
+        or beyond the bucket keep what the slot held before (zeros, or a
+        previous occupant's rows): they are masked until the decode onehot
+        overwrites them position by position."""
+        if not 0 <= slot < np.shape(next(iter(cache.values())))[0]:
+            raise IndexError(f"slot {slot} out of range")
+        with _trace.span("tokenpath.scatter.put"):
+            if any(isinstance(v, np.ndarray) for v in cache.values()):
+                _count_host_trip()
+                cache = jax.device_put(cache)
+            rows = jax.device_put({name: pcache[name][:, : buf.shape[1]] for name, buf in cache.items()})
+            if _trace.enabled:
+                rows = jax.block_until_ready(rows)
+        with _trace.span("tokenpath.scatter.dispatch"):
+            return self._write_rows(cache, rows, np.int32(slot))
 
     def decode(self, toks: np.ndarray, pos: np.ndarray, cache):
         return self.tp.decode_step(toks, pos, cache)

@@ -214,8 +214,8 @@ class TestNoTracer:
 
     def test_token_path_records_nothing_and_calls_no_hook(self, monkeypatch):
         """Served with no tracer installed, the token path opens no span,
-        calls no profiler hook, and neither puts to nor waits on the device
-        beyond what its jitted calls do."""
+        calls no profiler hook, never waits on the device, and puts only what
+        it must: the zero cache once, and each admission's K/V rows."""
         import jax
 
         from repro.serving.engine import EngineConfig, Request, ServeEngine
@@ -239,14 +239,14 @@ class TestNoTracer:
         tracer = obs_trace.Tracer(annotate=hook)
         serve()  # no tracer installed
         assert obs_trace.current() is None
-        assert hook.log == [] and tracer.records == [] and calls == []
-        # the same serving, traced, does call the hook and put the cache
+        assert hook.log == [] and tracer.records == [] and calls == ["device_put"] * (1 + 3)
+        # the same serving, traced, does call the hook and wait for the rows
         obs_trace.install(tracer)
         try:
             serve()
         finally:
             obs_trace.uninstall()
-        assert hook.log and tracer.spans("engine.step") and "device_put" in calls
+        assert hook.log and tracer.spans("engine.step") and "block_until_ready" in calls
 
     def test_uninstrumented_overhead_smoke(self):
         """The no-tracer fast path is a global read + a shared singleton;

@@ -17,6 +17,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -28,6 +29,7 @@ from repro.core.compile import compile_model
 from repro.core.patterns import build_exp_lut, emit_qattention
 from repro.core.runtime import ReferenceRuntime
 from repro.obs import trace as obs_trace
+from repro.obs.metrics import default_registry
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.serving.token_path import (
     CompiledTokenAdapter,
@@ -329,8 +331,8 @@ SPAN_PARENTS = {
     "run.execute": "engine.prefill",
     "run.slice": "engine.prefill",
     "engine.scatter": "engine.admit",
-    "tokenpath.scatter.fetch": "engine.scatter",
-    "tokenpath.scatter.write": "engine.scatter",
+    "tokenpath.scatter.put": "engine.scatter",
+    "tokenpath.scatter.dispatch": "engine.scatter",
     "engine.decode": "engine.step",
     "tokenpath.decode.put": "engine.decode",
     "tokenpath.decode.dispatch": "engine.decode",
@@ -343,9 +345,11 @@ class TestTokenPathSpans:
     """The serving loop's spans over the compiled token path, traced."""
 
     @staticmethod
-    def _serve(tracer=None, n=4):
+    def _serve(tracer=None, n=4, check=None):
         """Staggered requests over two slots, served cycle by cycle, with
-        ``tracer`` installed where given; returns the engine and its requests."""
+        ``tracer`` installed where given; returns the engine and its requests.
+        ``check(engine)``, where given, is read after every cycle into
+        ``engine.checked``."""
         tp = _tp("ref")
         eng = ServeEngine(
             ecfg=EngineConfig(slots=2, max_len=16, prefill_bucket=8),
@@ -356,6 +360,7 @@ class TestTokenPathSpans:
             Request(uid=i, prompt=_tokens(rng, 1, int(rng.integers(2, 8)))[0], max_new_tokens=3 + i)
             for i in range(n)
         ]
+        eng.checked = []
         if tracer is not None:
             obs_trace.install(tracer)
         try:
@@ -365,6 +370,8 @@ class TestTokenPathSpans:
                 if not eng.queue and not eng.active:
                     break
                 eng.step()
+                if check is not None:
+                    eng.checked.append(check(eng))
         finally:
             if tracer is not None:
                 obs_trace.uninstall()
@@ -376,7 +383,8 @@ class TestTokenPathSpans:
         by_sid = {r.sid: r for r in tracer.spans()}
         # first visits of a cell also open backend.specialize spans
         spans = [r for r in by_sid.values() if r.name in SPAN_PARENTS]
-        assert {r.name for r in spans} == set(SPAN_PARENTS)
+        # the cache stays on the device, so the decode never puts it there
+        assert {r.name for r in spans} == set(SPAN_PARENTS) - {"tokenpath.decode.put"}
         for r in spans:
             parent = by_sid[r.parent].name if r.parent is not None else None
             assert parent == SPAN_PARENTS[r.name], r
@@ -385,24 +393,18 @@ class TestTokenPathSpans:
         # request spans carry their uid
         assert sorted(s.attrs["uid"] for s in tracer.spans("engine.prefill")) == [r.uid for r in reqs]
         assert {s.attrs["uid"] for s in tracer.spans("engine.scatter")} == {r.uid for r in reqs}
-        # one fetch and one write per cache array per admission
-        n_arrays = len(eng.cache)
-        assert len(tracer.spans("tokenpath.scatter.fetch")) == n_arrays * len(reqs)
+        # one put and one dispatch per admission
+        assert len(tracer.spans("tokenpath.scatter.put")) == len(reqs)
+        assert len(tracer.spans("tokenpath.scatter.dispatch")) == len(reqs)
 
-    def test_the_cache_is_put_only_on_the_decode_after_an_admission(self):
+    def test_the_cache_stays_on_the_device(self):
         tracer = obs_trace.Tracer()
-        self._serve(tracer)
-        by_sid = {r.sid: r for r in tracer.spans()}
-        steps = {sid: set() for sid, r in by_sid.items() if r.name == "engine.step"}
-        for r in by_sid.values():
-            top = r
-            while top.parent is not None:
-                top = by_sid[top.parent]
-            steps[top.sid].add(r.name)
-        admitted = [("engine.scatter" in names) for names in steps.values()]
-        put = [("tokenpath.decode.put" in names) for names in steps.values()]
-        assert put == admitted
-        assert any(put) and not all(put)
+        trips = default_registry().counter("tokenpath.cache.host_trips")
+        before = trips.value
+        eng, _ = self._serve(tracer, check=lambda eng: all(isinstance(v, jax.Array) for v in eng.cache.values()))
+        assert eng.checked and all(eng.checked)
+        assert tracer.spans("engine.scatter") and not tracer.spans("tokenpath.decode.put")
+        assert trips.value == before
 
     def test_tokens_equal_with_and_without_the_tracer(self):
         _, plain = self._serve()
@@ -417,6 +419,56 @@ class TestTokenPathSpans:
         wait = eng.registry.histogram("engine.queue_wait_ms")
         assert wait.count == len(reqs)
         assert wait.max == pytest.approx(max(1e3 * (r.t_admit - r.t_submit) for r in reqs))
+
+
+class TestDeviceScatter:
+    """``CompiledTokenAdapter.scatter`` writes a prompt's rows into its slot
+    of the device cache, in place."""
+
+    S = 16
+
+    def _stale_cache(self, tp, on_device):
+        """A two-slot cache full of earlier occupants' rows."""
+        rng = np.random.default_rng(21)
+        cache = {k: rng.integers(-128, 128, v.shape).astype(np.int8) for k, v in tp.init_cache(2, self.S).items()}
+        return cache, (jax.device_put(cache) if on_device else {k: v.copy() for k, v in cache.items()})
+
+    @pytest.mark.parametrize("on_device", [True, False], ids=["device", "host"])
+    def test_equals_the_host_row_write_bit_for_bit(self, on_device):
+        tp = _tp("ref")
+        ad = CompiledTokenAdapter(tp)
+        plen, bucket, slot = 5, 8, 1
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :plen] = _tokens(np.random.default_rng(22), 1, plen)[0]
+        _, rows = ad.prefill(padded, plen, self.S)
+        want, cache = self._stale_cache(tp, on_device)
+        for name in want:
+            want[name][slot, :bucket] = rows[name][0]
+        trips = default_registry().counter("tokenpath.cache.host_trips")
+        before = trips.value
+        got = ad.scatter(cache, slot, rows)
+        assert trips.value - before == (0 if on_device else 1)
+        assert set(got) == set(want)
+        for name, buf in got.items():
+            assert isinstance(buf, jax.Array)
+            # the other slot, and this slot's stale rows beyond the bucket, survive
+            np.testing.assert_array_equal(np.asarray(buf), want[name])
+
+    def test_one_program_per_bucket_not_per_slot(self):
+        tp = _tp("ref")
+        ad = CompiledTokenAdapter(tp)
+        # every jit of ``_write_rows`` shares one cache, so the shapes here are
+        # this test's own: three slots of 24 positions
+        cache = ad.init_cache(3, 24)
+        rng = np.random.default_rng(23)
+        programs = []
+        for slot in (0, 1, 2):
+            rows = {k: rng.integers(-128, 128, (1, 8, CFG.d_model)).astype(np.int8) for k in cache}
+            cache = ad.scatter(cache, slot, rows)
+            programs.append(ad._write_rows._cache_size())
+        assert programs == programs[:1] * 3
+        with pytest.raises(IndexError):  # never clamped onto the last slot
+            ad.scatter(cache, 3, rows)
 
 
 class TestAttentionLane:
