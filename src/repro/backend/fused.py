@@ -16,6 +16,17 @@ Four kernel ids cover the paper's fusion patterns:
   ``ref`` backend runs the jnp oracle; ``interpret``/``pallas`` run the
   tiled kernel (:mod:`repro.kernels.qattention`).  Scalar constants ride in
   ``step.params`` (static under jit); the LUT is the one array const.
+* ``rmsnorm`` — the RMSNorm region (shared impl): its square root and
+  division corrected to the nearest f32, so that the chip computes the
+  artifact's IEEE semantics and not its own approximations.
+* ``softmax_rn`` — a sparse-expert router's softmax over its int32 logits
+  (shared impl): summed in expert order, the quotients the nearest f32.
+* ``qmoe`` — the routed-expert region of a sparse-expert block: rows,
+  chosen experts and router probabilities in, the fixed-point sum of the
+  chosen experts' weighted SwiGLU outputs out.  The ``ref`` backend runs the
+  dense oracle (every expert, zero weights where not chosen);
+  ``interpret``/``pallas`` run the grouped kernel
+  (:mod:`repro.kernels.qmoe`), which touches only the chosen experts.
 
 Step contract (see :mod:`repro.backend.plan`): ``args = [x]`` (the single
 graph-tensor input), parameters in ``step.consts``, static config in
@@ -158,3 +169,61 @@ def _qact_lut_interpret(step, args):
 @register("qact_lut", backend="pallas")
 def _qact_lut_pallas(step, args):
     return _qact_lut(step, args, backend="pallas")
+
+
+def _moe_rows(args):
+    """``(x (T, D), idx (T, K), probs (T, E))`` from the step's ``(N, S, ·)``
+    operands, and the leading shape to restore."""
+    x, idx, probs = args
+    lead = x.shape[:-1]
+    return (x.reshape(-1, x.shape[-1]), idx.reshape(-1, idx.shape[-1]),
+            probs.reshape(-1, probs.shape[-1]), lead)
+
+
+@register("rmsnorm")
+def _rmsnorm(step, args):
+    (gain,) = step.consts
+    p = step.params
+    return [_ref.rmsnorm_ref(args[0], gain, jnp.float32(p["inv_d"]), jnp.float32(p["eps"]))]
+
+
+@register("softmax_rn")
+def _softmax_rn(step, args):
+    return [_ref.softmax_rn(args[0].astype(jnp.float32) * jnp.float32(step.params["scale"]))]
+
+
+@register("qmoe", backend="ref")
+def _qmoe_ref(step, args):
+    x, idx, probs, lead = _moe_rows(args)
+    wg, wu, wd = step.consts
+    p = step.params
+    y = _ref.qmoe_ref(
+        x, idx, probs, wg, wu, wd,
+        r_g=jnp.float32(p["r_g"]), s_g=jnp.float32(p["s_g"]), r_u=jnp.float32(p["r_u"]),
+        r_h=jnp.float32(p["r_h"]), r_d=jnp.float32(p["r_d"]),
+    )
+    return [y.reshape(lead + (p["d"],))]
+
+
+def _qmoe_tiled(step, args, *, interpret: bool):
+    from ..kernels import qmoe as _qmoe
+
+    x, idx, probs, lead = _moe_rows(args)
+    wg, wu, wd = step.consts
+    p = step.params
+    y = _qmoe.qmoe(
+        x, idx, probs, wg, wu, wd,
+        d=p["d"], r_g=p["r_g"], s_g=p["s_g"], r_u=p["r_u"], r_h=p["r_h"], r_d=p["r_d"],
+        down_bits=p["down_bits"], interpret=interpret,
+    )
+    return [y.reshape(lead + (p["d"],))]
+
+
+@register("qmoe", backend="interpret")
+def _qmoe_interpret(step, args):
+    return _qmoe_tiled(step, args, interpret=True)
+
+
+@register("qmoe", backend="pallas")
+def _qmoe_pallas(step, args):
+    return _qmoe_tiled(step, args, interpret=False)

@@ -153,6 +153,32 @@ for _name, _fn in {
     _JOPS[_name] = _fn
 
 
+@_jop("Round")
+def _j_round(attrs, ins):
+    return [jnp.rint(ins[0])]
+
+
+@_jop("TopK")
+def _j_topk(attrs, ins):
+    """``jax.lax.top_k`` (equal values keep the lower index first) on the
+    last axis; indices come out int32 (the reference runtime's are int64)."""
+    x, k = ins[0], _static_ints(ins[1], "TopK", "k")[0]
+    if int(attrs.get("axis", -1)) not in (-1, x.ndim - 1) or not int(attrs.get("largest", 1)):
+        raise NotImplementedError("TopK lowers only largest-k along the last axis")
+    vals, idx = jax.lax.top_k(x, k)
+    return [vals, idx]
+
+
+@_jop("OneHot")
+def _j_one_hot(attrs, ins):
+    idx, depth, values = ins
+    if int(attrs.get("axis", -1)) != -1:
+        raise NotImplementedError("OneHot lowers only axis=-1")
+    d = _static_ints(depth, "OneHot", "depth")[0]
+    hot = idx.astype(jnp.int32)[..., None] == jnp.arange(d, dtype=jnp.int32)
+    return [jnp.where(hot, values[1], values[0]).astype(values.dtype)]
+
+
 @_jop("Gemm")
 def _j_gemm(attrs, ins):
     a, b = ins[0], ins[1]

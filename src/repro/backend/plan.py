@@ -68,12 +68,13 @@ from ..obs.provenance import PlanProvenance
 #: Arg kinds.
 SLOT, CONST, NONE = "slot", "const", "none"
 
-#: Consts of at least this many elements (weights, biases, scales, embedding
-#: tables) reach a jitted executor as arguments; smaller ones (shape operands,
-#: scalars, 256-entry LUTs) stay compile-time constants.  A closed-over
-#: parameter would be embedded in the program as a literal: compiled, and
-#: held on the device, once per specialization.
-PARAM_MIN_SIZE = 4096
+#: Consts of at least this many elements (weights, biases, scales, norm
+#: gains, embedding tables) reach a jitted executor as arguments; smaller
+#: ones (shape operands, scalars, 256-entry LUTs) stay compile-time
+#: constants.  A closed-over parameter would be embedded in the program as a
+#: literal: compiled, and held on the device, once per specialization, and
+#: compiled again for every other set of weights.
+PARAM_MIN_SIZE = 1024
 
 
 @dataclasses.dataclass(frozen=True)

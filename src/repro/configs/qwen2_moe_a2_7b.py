@@ -1,8 +1,14 @@
-"""qwen2-moe-a2.7b [moe] — 60 routed experts top-4 + shared expert
+"""qwen2-moe-a2.7b [moe] — 60 routed experts top-4 + one gated shared expert
 [hf:Qwen/Qwen1.5-MoE-A2.7B; hf].
 
-24L, d_model=2048, 16H (kv=16), vocab=151936, moe_intermediate=1408,
-shared_expert_intermediate=5632 (the "4 shared"), norm_topk_prob=False.
+24L, d_model=2048, 16H (kv=16), vocab=151936, moe_intermediate=1408, one
+shared expert of shared_expert_intermediate=5632, norm_topk_prob=False,
+tie_word_embeddings=False.
+
+This float config feeds the opaque model zoo only.  The compiled token path
+serves the model's block quantized (``TokenPathConfig(block="moe")`` in
+:mod:`repro.serving.token_path`, the ``qmoe`` kernel); the benchmark's
+``bench/configs/qwen1.5moe-a2.7b-4L.json`` gives the values it runs.
 """
 from .base import ModelConfig, MoEConfig
 
@@ -19,10 +25,10 @@ CONFIG = ModelConfig(
         n_experts=60,
         top_k=4,
         d_ff_expert=1408,
-        n_shared_experts=4,
+        n_shared_experts=1,
         d_ff_shared=5632,
         renormalize=False,
     ),
     mlp_type="swiglu",
-    tie_embeddings=True,
+    tie_embeddings=False,
 )

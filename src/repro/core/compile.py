@@ -32,6 +32,16 @@ three-level flow (QNN / onnx-mlir style multi-level lowering):
                        [Cast f32] → QuantizeLinear}
          ⇒ exact 256-entry VMEM LUT (repro.kernels.qact_lut), built with
            reference-runtime semantics (incl. the fp16 casts) ⇒ bit-exact.
+     QLINEAR_F32_PATTERN: {MatMulInteger → [Add] → Cast(f32) → Mul} whose
+                      f32 result feeds more f32 arithmetic ⇒ the fused
+                      kernel with an unrounded epilogue.
+
+   DAG regions (``REGIONS``, matched at their sinks by
+   :func:`repro.passes.rewrite.match_region`): ``QMOE_REGION``, the routed
+   experts of a sparse-expert block (every expert, zero weights where not
+   chosen) ⇒ the grouped ``qmoe`` kernel over the chosen experts only;
+   ``RMSNORM_REGION`` ⇒ one step with the nearest-f32 root and quotient;
+   ``ROUTER_SOFTMAX_REGION`` ⇒ one step summing in expert order.
 
 3. **Lower** — matches and fallback nodes become
    :class:`repro.backend.StepDraft`\\ s, and :func:`repro.backend.build_plan`
@@ -76,7 +86,8 @@ fallbacks are allclose.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+import weakref
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +97,9 @@ from ..backend import StepDraft, build_plan, const_arg, none_arg, specialize_pla
 from ..backend.generic import _JOPS  # noqa: F401  (re-export; conformance sweep)
 from ..backend.plan import ExecutionPlan, PlanCache, bindings_key, resolve_bucketing
 from ..kernels import ops as kops
+from ..kernels import pack as _pack
 from ..kernels.qact_lut import build_lut
+from ..kernels.ref import MOE_CLIP, MOE_FIXED
 from ..obs import trace as _trace
 from ..obs.metrics import MetricsRegistry
 from ..obs.provenance import PlanProvenance
@@ -100,7 +113,17 @@ from ..passes.analysis import (
     graph_axes,
     implicit_batch_graph,
 )
-from ..passes.rewrite import Match, OpSpec, Pattern, match_chain, ql_params
+from ..passes.rewrite import (
+    Match,
+    NodeSpec,
+    OpSpec,
+    Pattern,
+    Region,
+    RegionMatch,
+    match_chain,
+    match_region,
+    ql_params,
+)
 from . import runtime
 from .pqir import Model, Node
 
@@ -171,10 +194,32 @@ _QL_EPILOGUE = (
     OpSpec("QuantizeLinear", capture="ql", where=_is_round_clip_ql),
 )
 
+
+def _plain_weight(ga: GraphAnalysis, node: Node) -> bool:
+    """A matmul weight is one 2-D matrix (a stack of expert weights is the
+    routed-expert region's), a conv weight one 4-D kernel."""
+    w = ga.const(node.inputs[1])
+    return w is not None and w.ndim == (4 if node.op_type == "ConvInteger" else 2)
+
+
 QLINEAR_PATTERN = Pattern(
     "qlinear",
-    (OpSpec(("MatMulInteger", "ConvInteger"), capture="core", arity=2, const_inputs={1: "weight"}),)
+    (OpSpec(("MatMulInteger", "ConvInteger"), capture="core", arity=2, const_inputs={1: "weight"},
+            where=_plain_weight),)
     + _QL_EPILOGUE,
+)
+
+#: An int8 projection whose rescaled accumulator stays f32 and feeds more f32
+#: arithmetic (a sparse-expert block's shared expert and its sigmoid gate):
+#: the same fused kernel with an unrounded f32 epilogue.
+QLINEAR_F32_PATTERN = Pattern(
+    "qlinear_f32",
+    (
+        OpSpec("MatMulInteger", capture="core", arity=2, const_inputs={1: "weight"}, where=_plain_weight),
+        OpSpec("Add", capture="bias", optional=True, const_operand="bias_c"),
+        OpSpec("Cast", attrs={"to": "float32"}),
+        OpSpec("Mul", capture="mul1", const_operand="mul1_c"),
+    ),
 )
 
 #: Gemm-codified FC chains (some MLP exporters emit one integer Gemm instead
@@ -264,8 +309,17 @@ def _build_qlinear(compiler: "Compiler", m: Match) -> Optional[StepDraft]:
     # on the integer core op (weights stay an unpacked int8 initializer, so
     # the reference runtime needs no change); the tiled lowering packs on it.
     weight_bits = int(core.attrs.get("weight_bits", 8))
-    zp = ga.const(m.node("ql").inputs[2]) if len(m.node("ql").inputs) > 2 else np.zeros((), np.int8)
-    out_dtype = str(np.asarray(zp).dtype)
+    ql = m.node("ql")
+    if ql is None:
+        # the f32 lane: only where every reader continues in f32 arithmetic
+        if m.out_tensor in ga.out_names or not all(
+            c.op_type in ("Mul", "Sigmoid") for c in ga.consumers.get(m.out_tensor, [])
+        ):
+            return None
+        out_dtype = "float32"
+    else:
+        zp = ga.const(ql.inputs[2]) if len(ql.inputs) > 2 else np.zeros((), np.int8)
+        out_dtype = str(np.asarray(zp).dtype)
     relu = m.node("relu") is not None
 
     w = np.asarray(m.consts["weight"])
@@ -392,6 +446,229 @@ FUSIONS = (
     (QLINEAR_PATTERN, _build_qlinear),
     (GEMM_PATTERN, _build_qlinear),
     (LUT_PATTERN, _build_lut),
+    (QLINEAR_F32_PATTERN, _build_qlinear),
+)
+
+
+# ---------------------------------------------------------------------------
+# declarative DAG regions (repro.passes.rewrite.Region): routed experts, RMSNorm
+# ---------------------------------------------------------------------------
+
+
+def _is_round_clip_i8(ga: GraphAnalysis, node: Node) -> bool:
+    return _is_round_clip_ql(ga, node) and ga.dtype(node.outputs[0]) == "int8"
+
+
+def _stacked_weight(ga: GraphAnalysis, node: Node) -> bool:
+    w = ga.const(node.inputs[1])
+    return w is not None and w.ndim == 3 and w.dtype == np.int8
+
+
+def _expert_proj(name: str, rows: str):
+    return (
+        NodeSpec(f"{name}_acc", "MatMulInteger", (rows, f"#w_{name}"), where=_stacked_weight),
+        NodeSpec(f"{name}_f", "Cast", (f"@{name}_acc",), attrs={"to": "float32"}),
+        NodeSpec(f"{name}_s", "Mul", (f"@{name}_f", f"#r_{name}")),
+    )
+
+
+#: The routed-expert region ``repro.core.patterns.emit_moe_experts`` emits:
+#: every expert's SwiGLU on every row, weighted by the router's probability
+#: where chosen, contributions in fixed point summed over the experts.
+#: Fused onto the grouped ``qmoe`` kernel, which computes only the chosen
+#: experts — the same result, since an unchosen expert adds exactly 0.
+QMOE_REGION = Region(
+    "qmoe",
+    (
+        NodeSpec("rows", "Unsqueeze", ("$x", "#rows_axes")),
+        *_expert_proj("gate", "@rows"),
+        NodeSpec("gate_q", "QuantizeLinear", ("@gate_s", "#gate_ql_s", "#gate_ql_zp"), where=_is_round_clip_i8),
+        *_expert_proj("up", "@rows"),
+        NodeSpec("up_q", "QuantizeLinear", ("@up_s", "#up_ql_s", "#up_ql_zp"), where=_is_round_clip_i8),
+        NodeSpec("gf", "Cast", ("@gate_q",), attrs={"to": "float32"}),
+        NodeSpec("gx", "Mul", ("@gf", "#s_g")),
+        NodeSpec("sig", "Sigmoid", ("@gx",)),
+        NodeSpec("silu", "Mul", ("@gx", "@sig")),
+        NodeSpec("uf", "Cast", ("@up_q",), attrs={"to": "float32"}),
+        NodeSpec("prod", "Mul", ("@silu", "@uf")),
+        NodeSpec("prod_s", "Mul", ("@prod", "#r_h")),
+        NodeSpec("h", "QuantizeLinear", ("@prod_s", "#h_ql_s", "#h_ql_zp"), where=_is_round_clip_i8),
+        *_expert_proj("down", "@h"),
+        NodeSpec("hot", "OneHot", ("$idx", "#depth", "#hot_values"), attrs={"axis": -1}),
+        NodeSpec("chosen", "ReduceSum", ("@hot",), attrs={"axes": [2], "keepdims": 0}),
+        NodeSpec("weights", "Mul", ("$probs", "@chosen")),
+        NodeSpec("weights5", "Unsqueeze", ("@weights", "#weights_axes")),
+        NodeSpec("weighted", "Mul", ("@down_s", "@weights5")),
+        NodeSpec("fixed", "Mul", ("@weighted", "#fixed")),
+        NodeSpec("clipped", "Clip", ("@fixed", "#lo", "#hi")),
+        NodeSpec("rounded", "Round", ("@clipped",)),
+        NodeSpec("contrib", "Cast", ("@rounded",), attrs={"to": "int32"}),
+        NodeSpec("sum", "ReduceSum", ("@contrib",), attrs={"axes": [2, 3], "keepdims": 0}),
+    ),
+)
+
+
+def _scalar(c) -> Optional[float]:
+    c = np.asarray(c)
+    return float(c.reshape(())) if c.size == 1 and c.dtype == np.float32 else None
+
+
+#: Device copies of stacked expert weights, keyed by the ids of their host
+#: arrays (and the lane): a prefill and a decode plan compiled from the same
+#: weights hold one copy.  An entry goes when its host gate array does.
+_EXPERT_CONSTS: Dict[tuple, Tuple[tuple, tuple]] = {}
+
+
+def _expert_consts(sources, dense: bool, bits: int) -> tuple:
+    """The ``qmoe`` step's weight consts from the host ``(gate, up, down)``:
+    as they are for the dense ``ref`` oracle, else zero-padded to 128-lane
+    multiples (the down projection packed two per byte on the w4 lane)."""
+    key = tuple(id(a) for a in sources) + (dense, bits)
+    hit = _EXPERT_CONSTS.get(key)
+    if hit is not None and all(r() is a for r, a in zip(hit[0], sources)):
+        return hit[1]
+    wg, wu, wd = (np.asarray(a) for a in sources)
+    if dense:
+        consts = (jnp.asarray(wg), jnp.asarray(wu), jnp.asarray(wd))
+    else:
+        e, d, f = wg.shape
+        dp, fp = -(-d // 128) * 128, -(-f // 128) * 128
+        gp = np.zeros((e, dp, fp), np.int8)
+        up = np.zeros((e, dp, fp), np.int8)
+        dn = np.zeros((e, fp, dp), np.int8)
+        gp[:, :d, :f], up[:, :d, :f], dn[:, :f, :d] = wg, wu, wd
+        if bits == 4:
+            dn = np.stack([_pack.pack_int4(w) for w in dn])
+        consts = (jnp.asarray(gp), jnp.asarray(up), jnp.asarray(dn))
+    try:
+        refs = tuple(weakref.ref(a) for a in sources)
+    except TypeError:  # not weakly referable: no sharing
+        return consts
+    _EXPERT_CONSTS[key] = (refs, consts)
+    weakref.finalize(sources[0], _EXPERT_CONSTS.pop, key, None)
+    return consts
+
+
+def _build_qmoe(compiler: "Compiler", m: RegionMatch) -> Optional[StepDraft]:
+    """Lower a matched routed-expert region onto the grouped ``qmoe``
+    kernel.  The region's constants must be the codified ones (axes, the
+    {0, 1} one-hot, the fixed-point step and clip); the rescales ride in
+    ``params`` (static under jit), the stacked weights in ``consts``: as
+    they are for the ``ref`` oracle, zero-padded to 128-lane multiples
+    (with the down projection packed two per byte on the w4 lane) for the
+    tiled kernel."""
+    c = m.consts
+    wg, wu, wd = (np.asarray(c[k]) for k in ("w_gate", "w_up", "w_down"))
+    e, d, f = wg.shape
+    if wu.shape != (e, d, f) or wd.shape != (e, f, d) or wd.dtype != np.int8:
+        return None
+    if [int(a) for a in np.asarray(c["rows_axes"]).reshape(-1)] != [2, 3]:
+        return None
+    if [int(a) for a in np.asarray(c["weights_axes"]).reshape(-1)] != [3, 4]:
+        return None
+    if int(np.asarray(c["depth"]).reshape(-1)[0]) != e:
+        return None
+    if np.asarray(c["hot_values"]).tolist() != [0.0, 1.0]:
+        return None
+    if (_scalar(c["fixed"]), _scalar(c["lo"]), _scalar(c["hi"])) != (MOE_FIXED, -MOE_CLIP, MOE_CLIP):
+        return None
+    scales = {k: _scalar(c[k]) for k in ("r_gate", "s_g", "r_up", "r_h", "r_down")}
+    if any(v is None for v in scales.values()):
+        return None
+    bits = int(m.nodes["down_acc"].attrs.get("weight_bits", 8))
+    params = {
+        "d": int(d), "r_g": scales["r_gate"], "s_g": scales["s_g"], "r_u": scales["r_up"],
+        "r_h": scales["r_h"], "r_d": scales["r_down"], "down_bits": bits,
+    }
+    sources = (c["w_gate"], c["w_up"], c["w_down"])
+    consts = _expert_consts(sources, compiler.backend == "ref", bits)
+    return StepDraft(
+        "qmoe",
+        [tensor_arg(m.inputs["x"]), tensor_arg(m.inputs["idx"]), tensor_arg(m.inputs["probs"])],
+        [m.out_tensor],
+        params=params, consts=consts, kind="fused_qmoe", name=m.nodes["gate_acc"].name,
+    )
+
+
+def _reduces_last_axis(ga: GraphAnalysis, node: Node) -> bool:
+    shape = ga.shape(node.inputs[0])
+    axes = node.attrs.get("axes")
+    return shape is not None and axes is not None and [int(a) % len(shape) for a in axes] == [len(shape) - 1]
+
+
+#: The RMSNorm region ``repro.core.patterns.emit_rmsnorm`` emits, fused onto
+#: one step whose square root and division are the nearest f32 on every
+#: backend (a TPU's own are not correctly rounded).
+RMSNORM_REGION = Region(
+    "rmsnorm",
+    (
+        NodeSpec("xi", "Cast", ("$x",), attrs={"to": "int32"}),
+        NodeSpec("sq", "Mul", ("@xi", "@xi")),
+        NodeSpec("ss", "ReduceSum", ("@sq",), attrs={"keepdims": 1}, where=_reduces_last_axis),
+        NodeSpec("ssf", "Cast", ("@ss",), attrs={"to": "float32"}),
+        NodeSpec("ms", "Mul", ("@ssf", "#inv_d")),
+        NodeSpec("var", "Add", ("@ms", "#eps")),
+        NodeSpec("rms", "Sqrt", ("@var",)),
+        NodeSpec("xf", "Cast", ("$x",), attrs={"to": "float32"}),
+        NodeSpec("unit", "Div", ("@xf", "@rms")),
+        NodeSpec("scaled", "Mul", ("@unit", "#gain")),
+        NodeSpec("out", "QuantizeLinear", ("@scaled", "#ql_s", "#ql_zp"), where=_is_round_clip_i8),
+    ),
+)
+
+
+def _build_rmsnorm(compiler: "Compiler", m: RegionMatch) -> Optional[StepDraft]:
+    c = m.consts
+    inv_d, eps = _scalar(c["inv_d"]), _scalar(c["eps"])
+    gain = np.asarray(c["gain"])
+    d = (compiler.analysis.shape(m.inputs["x"]) or (None,))[-1]
+    if inv_d is None or eps is None or gain.dtype != np.float32 or gain.shape != (d,):
+        return None
+    if compiler.analysis.dtype(m.inputs["x"]) != "int8":
+        return None
+    return StepDraft(
+        "rmsnorm", [tensor_arg(m.inputs["x"])], [m.out_tensor],
+        params={"inv_d": inv_d, "eps": eps}, consts=(jnp.asarray(gain),),
+        kind="fused_norm", name=m.nodes["xi"].name,
+    )
+
+
+def _softmax_last_axis(ga: GraphAnalysis, node: Node) -> bool:
+    shape = ga.shape(node.inputs[0])
+    return shape is not None and int(node.attrs.get("axis", -1)) in (-1, len(shape) - 1)
+
+
+#: The router's softmax ``repro.core.patterns.emit_router`` emits (its int32
+#: logits in f32, times the router's scale, normalized over the experts),
+#: fused onto one step that sums in expert order and takes the nearest f32
+#: quotients (:func:`repro.kernels.ref.softmax_rn`): the same weights at
+#: every shape the plan is specialized to, where XLA's own softmax reduces
+#: in a shape-dependent order.
+ROUTER_SOFTMAX_REGION = Region(
+    "router_softmax",
+    (
+        NodeSpec("f", "Cast", ("$logits",), attrs={"to": "float32"}),
+        NodeSpec("scaled", "Mul", ("@f", "#scale")),
+        NodeSpec("probs", "Softmax", ("@scaled",), where=_softmax_last_axis),
+    ),
+)
+
+
+def _build_router_softmax(compiler: "Compiler", m: RegionMatch) -> Optional[StepDraft]:
+    scale = _scalar(m.consts["scale"])
+    if scale is None or compiler.analysis.dtype(m.inputs["logits"]) != "int32":
+        return None
+    return StepDraft(
+        "softmax_rn", [tensor_arg(m.inputs["logits"])], [m.out_tensor],
+        params={"scale": scale}, kind="fused_router", name=m.nodes["f"].name,
+    )
+
+
+#: DAG regions fused at their sinks: (region, step builder).
+REGIONS = (
+    (QMOE_REGION, _build_qmoe),
+    (RMSNORM_REGION, _build_rmsnorm),
+    (ROUTER_SOFTMAX_REGION, _build_router_softmax),
 )
 
 
@@ -764,6 +1041,9 @@ class Compiler:
             "fused_qconv": 0,
             "fused_lut": 0,
             "fused_qattention": 0,
+            "fused_qmoe": 0,
+            "fused_norm": 0,
+            "fused_router": 0,
             "generic": 0,
             "folded": self.pass_report.total("folded"),
             "eliminated": self.pass_report.total("eliminated"),
@@ -782,6 +1062,9 @@ class Compiler:
         attn_emit, attn_skip = ({}, set())
         if self.fuse:
             attn_emit, attn_skip = self._qattention_regions()
+            region_emit, region_skip = self._dag_regions()
+            attn_emit.update(region_emit)
+            attn_skip |= region_skip
         with _trace.span("compile.fuse", nodes=len(order)) as fuse_span:
             for node in order:
                 if id(node) in consumed or id(node) in attn_skip:
@@ -839,6 +1122,30 @@ class Compiler:
                 "qattention", node.name,
                 tuple(n.name for n in qm["nodes"]), qm["out"],
             )
+        return emit, skip
+
+    def _dag_regions(self):
+        """Match every declarative region of :data:`REGIONS` at its sink,
+        as :meth:`_qattention_regions` does for attention: ``emit`` maps the
+        sink's id to the fused step, ``skip`` holds the other members."""
+        emit: Dict[int, StepDraft] = {}
+        skip: set = set()
+        for region, builder in REGIONS:
+            for node in self.graph.nodes:
+                if node.op_type != region.sink.op or id(node) in emit or id(node) in skip:
+                    continue
+                rm = match_region(self.analysis, node, region)
+                if rm is None:
+                    continue
+                draft = builder(self, rm)
+                if draft is None:
+                    continue
+                emit[id(node)] = draft
+                members = rm.members()
+                skip.update(id(n) for n in members if n is not node)
+                self.provenance.add_fusion(
+                    region.name, draft.name, tuple(n.name for n in members), rm.out_tensor,
+                )
         return emit, skip
 
     def _fused_draft(self, node: Node, consumed: set) -> Optional[StepDraft]:

@@ -20,6 +20,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..kernels.ref import MOE_CLIP, MOE_FIXED
 from .pqir import GraphBuilder
 from .quant import QuantizedLinearParams, Rescale
 
@@ -317,3 +318,179 @@ def fc_fp16_sigmoid(
     s = gb.op("Sigmoid", [h], out_hint=f"{prefix}_sig16")
     f = gb.op("Cast", [s], out_hint=f"{prefix}_back32", to="float32")
     return _ql(gb, f, 1.0 / 255.0, prefix, "uint8")
+
+
+# ---------------------------------------------------------------------------
+# decoder-block regions: RMSNorm, rotary positions, SwiGLU, routed experts
+# ---------------------------------------------------------------------------
+
+#: Fixed-point step of the rotary tables: cos/sin as int16 codes of 2**-14.
+ROPE_FRAC_BITS = 14
+
+
+def emit_rmsnorm(gb: GraphBuilder, x: str, gain: np.ndarray, eps_codes: float, prefix: str) -> str:
+    """RMSNorm of int8 codes ``x (N, S, D)`` as an f32 region between
+    quantized linears: the sum of squares in int32 (exact), its mean plus
+    ``eps`` in code units (``eps / s_in**2``), ``x / sqrt(.)`` in f32, times
+    ``gain = γ / s_out`` per feature, rounded to int8.  The input scale
+    cancels, so only ``eps`` carries it."""
+    d = int(np.asarray(gain).size)
+    xi = gb.op("Cast", [x], out_hint=f"{prefix}_xi", to="int32")
+    sq = gb.op("Mul", [xi, xi], out_hint=f"{prefix}_sq")
+    ss = gb.op("ReduceSum", [sq], out_hint=f"{prefix}_ss", axes=[2], keepdims=1)
+    ssf = gb.op("Cast", [ss], out_hint=f"{prefix}_ssf", to="float32")
+    inv_d = gb.add_initializer(f"{prefix}_inv_d", np.float32(1.0 / d))
+    ms = gb.op("Mul", [ssf, inv_d], out_hint=f"{prefix}_ms")
+    eps = gb.add_initializer(f"{prefix}_eps", np.float32(eps_codes))
+    den = gb.op("Sqrt", [gb.op("Add", [ms, eps], out_hint=f"{prefix}_var")], out_hint=f"{prefix}_rms")
+    xf = gb.op("Cast", [x], out_hint=f"{prefix}_xf", to="float32")
+    r = gb.op("Div", [xf, den], out_hint=f"{prefix}_unit")
+    g = gb.add_initializer(f"{prefix}_gain", np.asarray(gain, np.float32))
+    return emit_round_clip(gb, gb.op("Mul", [r, g], out_hint=f"{prefix}_scaled"), prefix)
+
+
+def rope_tables(max_pos: int, n_heads: int, d_head: int, theta: float):
+    """Rotate-half rotary tables over the concatenated heads, as int16 codes
+    of ``2**-ROPE_FRAC_BITS``: ``cos (P, H·dh)``, ``sin (P, H·dh)`` with the
+    rotate-half sign folded in, and the feature permutation ``perm (H·dh,)``
+    that pairs feature ``i`` with ``i ± dh/2`` inside its head, so that
+    ``rope(x) = x·cos + x[perm]·sin``."""
+    half = d_head // 2
+    inv = 1.0 / (float(theta) ** (np.arange(half, dtype=np.float64) * 2.0 / d_head))
+    ang = np.arange(max_pos, dtype=np.float64)[:, None] * inv[None, :]  # (P, half)
+    one = float(1 << ROPE_FRAC_BITS)
+    cos = np.rint(np.cos(ang) * one)
+    sin = np.rint(np.sin(ang) * one)
+    cos_h = np.concatenate([cos, cos], axis=1)
+    sin_h = np.concatenate([-sin, sin], axis=1)  # rotate_half(x) = (-x2, x1)
+    cos_t = np.tile(cos_h, (1, n_heads)).astype(np.int16)
+    sin_t = np.tile(sin_h, (1, n_heads)).astype(np.int16)
+    within = np.concatenate([np.arange(half, d_head), np.arange(0, half)])
+    perm = (np.arange(n_heads)[:, None] * d_head + within[None, :]).reshape(-1).astype(np.int64)
+    return cos_t, sin_t, perm
+
+
+def emit_rope_tables(gb: GraphBuilder, positions: str, cos: np.ndarray, sin: np.ndarray, prefix: str):
+    """The rotary table rows at ``positions (N, S)``, as int32 ``(N, S, D)``
+    (one gather per graph, shared by every layer's q and k)."""
+    out = []
+    for name, table in (("cos", cos), ("sin", sin)):
+        t = gb.add_initializer(f"{prefix}_{name}_q", table)
+        rows = gb.op("Gather", [t, positions], out_hint=f"{prefix}_{name}_rows", axis=0)
+        out.append(gb.op("Cast", [rows], out_hint=f"{prefix}_{name}", to="int32"))
+    return tuple(out)
+
+
+def emit_rope(gb: GraphBuilder, x: str, cos: str, sin: str, perm: np.ndarray, prefix: str) -> str:
+    """Rotary positions on int8 codes ``x (N, S, D)``, in fixed point:
+    ``x·cos + x[perm]·sin`` in int32 (exact), times ``2**-14``, rounded to
+    int8 on the same scale (a rotation keeps the norm)."""
+    xi = gb.op("Cast", [x], out_hint=f"{prefix}_xi", to="int32")
+    p = gb.add_initializer(f"{prefix}_perm", np.asarray(perm, np.int64))
+    xr = gb.op("Gather", [xi, p], out_hint=f"{prefix}_rot", axis=2)
+    a = gb.op("Mul", [xi, cos], out_hint=f"{prefix}_xcos")
+    b = gb.op("Mul", [xr, sin], out_hint=f"{prefix}_xsin")
+    s = gb.op("Add", [a, b], out_hint=f"{prefix}_sum")
+    f = gb.op("Cast", [s], out_hint=f"{prefix}_f", to="float32")
+    step = gb.add_initializer(f"{prefix}_step", np.float32(2.0 ** -ROPE_FRAC_BITS))
+    return emit_round_clip(gb, gb.op("Mul", [f, step], out_hint=f"{prefix}_scaled"), prefix)
+
+
+def emit_swiglu(gb: GraphBuilder, g: str, u: str, s_g: float, r_h: float, prefix: str) -> str:
+    """The SwiGLU product of int8 gate and up codes: the gate dequantized
+    (``× s_g``) through an f32 SiLU ``x · sigmoid(x)``, times the up code,
+    rescaled by ``r_h = s_u / s_h`` and rounded to int8 (op order as
+    :func:`repro.kernels.ref.swiglu_ref`)."""
+    gf = gb.op("Cast", [g], out_hint=f"{prefix}_gf", to="float32")
+    sg = gb.add_initializer(f"{prefix}_s_g", np.float32(s_g))
+    gx = gb.op("Mul", [gf, sg], out_hint=f"{prefix}_gx")
+    sig = gb.op("Sigmoid", [gx], out_hint=f"{prefix}_sig")
+    si = gb.op("Mul", [gx, sig], out_hint=f"{prefix}_silu")
+    uf = gb.op("Cast", [u], out_hint=f"{prefix}_uf", to="float32")
+    p = gb.op("Mul", [si, uf], out_hint=f"{prefix}_prod")
+    rh = gb.add_initializer(f"{prefix}_r_h", np.float32(r_h))
+    return emit_round_clip(gb, gb.op("Mul", [p, rh], out_hint=f"{prefix}_scaled"), prefix)
+
+
+def fc_layer_f32(gb: GraphBuilder, x: str, p: QuantizedLinearParams, prefix: str) -> str:
+    """An int8 projection whose rescaled accumulator stays f32 (no rounding):
+    MatMulInteger [→ Add bias] → Cast f32 → Mul(multiplier)."""
+    w = gb.add_initializer(f"{prefix}_weight_q", p.weight_q)
+    attrs = {"weight_bits": p.bits} if p.bits != 8 else {}
+    acc = gb.op("MatMulInteger", [x, w], out_hint=f"{prefix}_acc", **attrs)
+    if p.bias_q is not None:
+        acc = gb.op("Add", [acc, gb.add_initializer(f"{prefix}_bias_q", p.bias_q)], out_hint=f"{prefix}_biased")
+    return emit_rescale(gb, acc, p.rescale, prefix, two_mul=False)
+
+
+def emit_router(gb: GraphBuilder, x: str, w_router: np.ndarray, scale: float, top_k: int, prefix: str):
+    """Router of a sparse-expert block: int8 × w8 → int32 logits ``(N, S,
+    E)``; the top ``top_k`` experts chosen on those integers (exact; equal
+    logits keep the lower expert index, the ONNX TopK rule) as int32 indices;
+    the weights from the f32 softmax of ``logits · scale``.  Returns
+    ``(indices, probs)``."""
+    w = gb.add_initializer(f"{prefix}_weight_q", np.asarray(w_router, np.int8))
+    acc = gb.op("MatMulInteger", [x, w], out_hint=f"{prefix}_logits")
+    k = gb.add_initializer(f"{prefix}_k", np.array([top_k], np.int64))
+    vals, idx = gb.fresh(f"{prefix}_top_vals"), gb.fresh(f"{prefix}_top_idx")
+    gb.add_node("TopK", [acc, k], [vals, idx], axis=-1, largest=1, sorted=1)
+    idx32 = gb.op("Cast", [idx], out_hint=f"{prefix}_experts", to="int32")
+    f = gb.op("Cast", [acc], out_hint=f"{prefix}_logits_f", to="float32")
+    sc = gb.add_initializer(f"{prefix}_scale", np.float32(scale))
+    probs = gb.op("Softmax", [gb.op("Mul", [f, sc], out_hint=f"{prefix}_scaled")], out_hint=f"{prefix}_probs", axis=-1)
+    return idx32, probs
+
+
+def emit_moe_experts(
+    gb: GraphBuilder,
+    x: str,  # (N, S, D) int8 rows
+    idx: str,  # (N, S, K) int32 chosen experts
+    probs: str,  # (N, S, E) f32 router softmax
+    w_gate: np.ndarray,  # (E, D, F) int8
+    w_up: np.ndarray,  # (E, D, F) int8
+    w_down: np.ndarray,  # (E, F, D) int8
+    prefix: str,
+    *,
+    r_g: float,
+    s_g: float,
+    r_u: float,
+    r_h: float,
+    r_d: float,
+    bits_down: int = 4,
+) -> str:
+    """The routed-expert region in its exact semantic form: every expert on
+    every row (stacked ``(E, ·, ·)`` weights), each expert's output weighted
+    by its router probability where it was chosen and by zero elsewhere,
+    rounded to ``1 / MOE_FIXED`` of a code and summed over the experts in
+    int32.  Returns ``(N, S, D)`` int32.  The compiler fuses the whole
+    region onto the grouped ``qmoe`` kernel, which computes only the chosen
+    experts; the reference runtime executes it as written."""
+    e = int(w_gate.shape[0])
+    ax = gb.add_initializer(f"{prefix}_x_axes", np.array([2, 3], np.int64))
+    xh = gb.op("Unsqueeze", [x, ax], out_hint=f"{prefix}_rows")  # (N, S, 1, 1, D)
+
+    def proj(inp, w, name, r, bits=8):
+        wq = gb.add_initializer(f"{prefix}_{name}_q", np.asarray(w, np.int8))
+        attrs = {"weight_bits": bits} if bits != 8 else {}
+        acc = gb.op("MatMulInteger", [inp, wq], out_hint=f"{prefix}_{name}_acc", **attrs)
+        f = gb.op("Cast", [acc], out_hint=f"{prefix}_{name}_f", to="float32")
+        return gb.op("Mul", [f, gb.add_initializer(f"{prefix}_{name}_r", np.float32(r))], out_hint=f"{prefix}_{name}_s")
+
+    g = emit_round_clip(gb, proj(xh, w_gate, "gate", r_g), f"{prefix}_gate")
+    u = emit_round_clip(gb, proj(xh, w_up, "up", r_u), f"{prefix}_up")
+    h = emit_swiglu(gb, g, u, s_g, r_h, f"{prefix}_glu")  # (N, S, E, 1, F)
+    y = proj(h, w_down, "down", r_d, bits_down)  # (N, S, E, 1, D) f32
+    depth = gb.add_initializer(f"{prefix}_depth", np.array([e], np.int64))
+    values = gb.add_initializer(f"{prefix}_hot_values", np.array([0.0, 1.0], np.float32))
+    hot = gb.op("OneHot", [idx, depth, values], out_hint=f"{prefix}_hot", axis=-1)  # (N, S, K, E)
+    chosen = gb.op("ReduceSum", [hot], out_hint=f"{prefix}_chosen", axes=[2], keepdims=0)
+    w = gb.op("Mul", [probs, chosen], out_hint=f"{prefix}_weights")
+    wax = gb.add_initializer(f"{prefix}_w_axes", np.array([3, 4], np.int64))
+    w5 = gb.op("Unsqueeze", [w, wax], out_hint=f"{prefix}_weights5")  # (N, S, E, 1, 1)
+    c = gb.op("Mul", [y, w5], out_hint=f"{prefix}_weighted")
+    c = gb.op("Mul", [c, gb.add_initializer(f"{prefix}_fixed", np.float32(MOE_FIXED))], out_hint=f"{prefix}_fixed_f")
+    lo = gb.add_initializer(f"{prefix}_lo", np.float32(-MOE_CLIP))
+    hi = gb.add_initializer(f"{prefix}_hi", np.float32(MOE_CLIP))
+    c = gb.op("Round", [gb.op("Clip", [c, lo, hi], out_hint=f"{prefix}_clipped")], out_hint=f"{prefix}_rounded")
+    ci = gb.op("Cast", [c], out_hint=f"{prefix}_contrib", to="int32")
+    return gb.op("ReduceSum", [ci], out_hint=f"{prefix}_sum", axes=[2, 3], keepdims=0)

@@ -68,6 +68,10 @@ STANDARD_OPS = frozenset(
         "Sqrt",
         "Pow",
         "Clip",
+        "Round",
+        # sparse-expert routing
+        "TopK",
+        "OneHot",
     }
 )
 

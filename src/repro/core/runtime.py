@@ -205,6 +205,37 @@ def _softmax(node: Node, inputs):
     return [(e / e.sum(axis=axis, keepdims=True)).astype(inputs[0].dtype)]
 
 
+@op("Round")
+def _round(node: Node, inputs):
+    # ONNX Round: half to even, like np.rint
+    return [np.rint(inputs[0]).astype(inputs[0].dtype)]
+
+
+@op("TopK")
+def _topk(node: Node, inputs):
+    """Largest (or smallest) ``k`` along ``axis``, sorted; equal values keep
+    the lower index first (the ONNX tie rule).  Indices are int64."""
+    x, k = inputs[0], int(np.asarray(inputs[1]).reshape(-1)[0])
+    axis = int(node.attrs.get("axis", -1))
+    key = x.astype(np.float64) if np.issubdtype(x.dtype, np.floating) else x.astype(np.int64)
+    if int(node.attrs.get("largest", 1)):
+        key = -key
+    idx = np.take(np.argsort(key, axis=axis, kind="stable"), np.arange(k), axis=axis)
+    return [np.take_along_axis(x, idx, axis=axis), idx.astype(np.int64)]
+
+
+@op("OneHot")
+def _one_hot(node: Node, inputs):
+    """``values[1]`` where the new trailing axis equals the index, else
+    ``values[0]`` (``axis`` -1 only)."""
+    idx, depth, values = inputs
+    if int(node.attrs.get("axis", -1)) != -1:
+        raise NotImplementedError("OneHot supports axis=-1 only")
+    d = int(np.asarray(depth).reshape(-1)[0])
+    hot = idx.astype(np.int64)[..., None] == np.arange(d)
+    return [np.where(hot, values[1], values[0]).astype(values.dtype)]
+
+
 # -- float compute -----------------------------------------------------------
 
 

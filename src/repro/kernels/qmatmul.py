@@ -97,6 +97,8 @@ def _epilogue(acc, bias, qscale, qshift, *, relu: bool, two_mul: bool, out_dtype
         f = f * qshift
     if relu:
         f = jnp.maximum(f, 0.0)
+    if jnp.issubdtype(out_dtype, jnp.floating):
+        return f.astype(out_dtype)  # the f32 lane: no QuantizeLinear
     r = jnp.rint(f)  # round half to even, as ONNX QuantizeLinear
     info = jnp.iinfo(out_dtype)
     return jnp.clip(r, info.min, info.max).astype(out_dtype)

@@ -36,6 +36,8 @@ def qmatmul_ref(
         f = f * (quant_shift.reshape((1,) * (f.ndim - 1) + (-1,)) if quant_shift.ndim else quant_shift)
     if relu:
         f = jnp.maximum(f, 0.0)
+    if jnp.issubdtype(out_dtype, jnp.floating):
+        return f.astype(out_dtype)
     r = jnp.rint(f)
     info = jnp.iinfo(out_dtype)
     return jnp.clip(r, info.min, info.max).astype(out_dtype)
@@ -79,6 +81,66 @@ def div_rn(a: jax.Array, b: jax.Array) -> jax.Array:
     corrections change nothing."""
     q = _nearest(a, b, _nearest(a, b, a / b))
     return jnp.where(a == 0, jnp.float32(0.0), q)
+
+
+def div_signed(a: jax.Array, b: jax.Array) -> jax.Array:
+    """IEEE ``a / b`` for any sign of ``a`` and ``b > 0`` (:func:`div_rn` of
+    the magnitude; rounding to nearest is symmetric)."""
+    return jnp.where(a < 0, -div_rn(-a, b), div_rn(a, b))
+
+
+def sqrt_rn(a: jax.Array) -> jax.Array:
+    """The f32 root of ``a >= 0`` that is nearest ``sqrt(a)`` in the sense
+    ``|a - c·c|`` least (ties: even), on any backend: the hardware root and
+    its neighbours up to two ulps away, compared by their exact residuals.
+    TPU square roots are not correctly rounded; this makes the result a
+    function of ``a`` alone, within one ulp of the true root."""
+    return _nearest_root(a, jnp.sqrt(a))
+
+
+def _nearest_root(a: jax.Array, s: jax.Array) -> jax.Array:
+    """:func:`sqrt_rn` from a root ``s`` within two ulps of the true one."""
+    bits = jax.lax.bitcast_convert_type(s, jnp.int32)
+
+    def residual(c):
+        p = c * c
+        ch, cl = _split(c)
+        return jnp.abs((a - p) - (((ch * ch - p) + 2.0 * (ch * cl)) + cl * cl))
+
+    best, best_r = s, residual(s)
+    for d in (-2, -1, 1, 2):
+        cb = jnp.maximum(bits + d, 0)
+        c = jax.lax.bitcast_convert_type(cb, jnp.float32)
+        r = residual(c)
+        better = (r < best_r) | ((r == best_r) & ((cb & 1) == 0))
+        best, best_r = jnp.where(better, c, best), jnp.where(better, r, best_r)
+    return jnp.where(a == 0, jnp.float32(0.0), best)
+
+
+def softmax_rn(x: jax.Array) -> jax.Array:
+    """Softmax over the last axis whose value depends on ``x`` alone, not on
+    the array's shape: ``exp(x - max)`` summed in index order, each quotient
+    the nearest f32 (:func:`div_rn`).  XLA's own softmax on a TPU reduces in
+    a shape-dependent order (the same router logits gave different weights
+    in a ``(32, 1, 60)`` decode and a ``(2, 1024, 60)`` batch)."""
+    e = jnp.exp(x - jnp.max(x, axis=-1, keepdims=True))
+    s = e[..., 0]
+    for i in range(1, e.shape[-1]):
+        s = s + e[..., i]
+    return div_rn(e, s[..., None])
+
+
+def rmsnorm_ref(x_q: jax.Array, gain: jax.Array, inv_d, eps) -> jax.Array:
+    """RMSNorm of int8 codes ``(..., D)`` as the artifact codifies it
+    (``repro.core.patterns.emit_rmsnorm``): the int32 sum of squares, its
+    mean plus ``eps`` in f32, ``x / sqrt(.)`` and ``× gain``, rounded to int8.
+    The root and the quotient are the nearest f32 on every backend
+    (:func:`sqrt_rn`, :func:`div_signed`)."""
+    xi = x_q.astype(jnp.int32)
+    ss = jnp.sum(xi * xi, axis=-1, keepdims=True, dtype=jnp.int32)
+    den = sqrt_rn(ss.astype(jnp.float32) * inv_d + eps)
+    y = div_signed(x_q.astype(jnp.float32), den) * gain
+    return jnp.clip(jnp.rint(y), -128, 127).astype(jnp.int8)
 
 
 def qact_lut_ref(x_q: jax.Array, lut: jax.Array) -> jax.Array:
@@ -128,3 +190,64 @@ def qattention_ref(
     f = ctx.astype(jnp.float32) * rescale
     info = jnp.iinfo(out_dtype)
     return jnp.clip(jnp.rint(f), info.min, info.max).astype(out_dtype)
+
+
+#: Fixed-point step of the expert combine: each weighted expert output is
+#: rounded to ``1 / MOE_FIXED`` of an activation code and summed in int32,
+#: which is exact in any order.  ``MOE_CLIP`` bounds it before the cast.
+MOE_FIXED = 256.0
+MOE_CLIP = 2.0**30
+
+
+def _round_clip8(f: jax.Array) -> jax.Array:
+    """QuantizeLinear(scale=1, zp=int8 0) as an f32 value: round half to even
+    and clip to the int8 range."""
+    return jnp.clip(jnp.rint(f), -128.0, 127.0)
+
+
+def swiglu_ref(g_acc, u_acc, r_g, s_g, r_u, r_h) -> jax.Array:
+    """The codified SwiGLU product of one expert, from its gate and up int32
+    accumulators: both rescaled and rounded to int8 codes, the gate
+    dequantized (``× s_g``) through an f32 SiLU ``x · sigmoid(x)``, times the
+    up code, rescaled by ``r_h`` and rounded to int8."""
+    gx = _round_clip8(g_acc.astype(jnp.float32) * r_g) * s_g
+    si = gx * jax.nn.sigmoid(gx)
+    u = _round_clip8(u_acc.astype(jnp.float32) * r_u)
+    return _round_clip8((si * u) * r_h).astype(jnp.int8)
+
+
+def combine_ref(d_acc, weight, r_d) -> jax.Array:
+    """One expert's down-projection accumulator as its weighted fixed-point
+    contribution: ``rint(clip(acc · r_d · weight · MOE_FIXED))`` in int32."""
+    c = (d_acc.astype(jnp.float32) * r_d) * weight
+    c = jnp.clip(c * jnp.float32(MOE_FIXED), -MOE_CLIP, MOE_CLIP)
+    return jnp.rint(c).astype(jnp.int32)
+
+
+def route_weights(idx: jax.Array, probs: jax.Array) -> jax.Array:
+    """Dense per-expert weights ``(..., E)``: the softmax probability of each
+    chosen expert (``idx (..., K)``), zero for every other one."""
+    chosen = jax.nn.one_hot(idx, probs.shape[-1], dtype=jnp.float32).sum(axis=-2)
+    return probs * chosen
+
+
+def qmoe_ref(
+    x_q: jax.Array,  # (T, D) int8 rows
+    idx: jax.Array,  # (T, K) chosen experts
+    probs: jax.Array,  # (T, E) f32 router softmax
+    w_gate: jax.Array,  # (E, D, F) int8
+    w_up: jax.Array,  # (E, D, F) int8
+    w_down: jax.Array,  # (E, F, D) int8 (int4 values on the w4 lane)
+    *,
+    r_g, s_g, r_u, r_h, r_d,
+) -> jax.Array:
+    """Routed-expert oracle: the region's semantic form, every expert on
+    every row with weight zero where it was not chosen, contributions summed
+    in int32.  Returns ``(T, D)`` int32 in ``1 / MOE_FIXED`` code units."""
+    x = x_q.astype(jnp.int32)
+    g = jnp.einsum("td,edf->tef", x, w_gate.astype(jnp.int32), preferred_element_type=jnp.int32)
+    u = jnp.einsum("td,edf->tef", x, w_up.astype(jnp.int32), preferred_element_type=jnp.int32)
+    h = swiglu_ref(g, u, r_g, s_g, r_u, r_h).astype(jnp.int32)
+    d = jnp.einsum("tef,efd->ted", h, w_down.astype(jnp.int32), preferred_element_type=jnp.int32)
+    w = route_weights(idx, probs)[..., None]
+    return combine_ref(d, w, r_d).sum(axis=1, dtype=jnp.int32)

@@ -50,7 +50,7 @@ Shape = Optional[Tuple[SymDim, ...]]
 BATCH_AXIS = "N"
 
 _UNARY_PASSTHROUGH = frozenset(
-    {"Relu", "Tanh", "Sigmoid", "Erf", "Sqrt", "Softmax", "Clip", "Identity"}
+    {"Relu", "Tanh", "Sigmoid", "Erf", "Sqrt", "Softmax", "Clip", "Identity", "Round"}
 )
 _BINARY_PROMOTE = frozenset({"Mul", "Add", "Sub", "Div", "Pow"})
 
@@ -83,6 +83,8 @@ def infer_dtypes(graph: Graph) -> Dict[str, str]:
             dt[o] = node.attrs["to"]
         elif t == "Shape":
             dt[o] = "int64"
+        elif t == "OneHot":
+            dt[o] = dt.get(node.inputs[2], "float32")
         elif t in _BINARY_PROMOTE and len(node.inputs) >= 2:
             a, b = dt.get(node.inputs[0]), dt.get(node.inputs[1])
             if a is not None and b is not None:
@@ -93,6 +95,8 @@ def infer_dtypes(graph: Graph) -> Dict[str, str]:
             dt[o] = dt.get(node.inputs[0], "float32") if node.inputs else "float32"
         for extra in node.outputs[1:]:
             dt[extra] = dt[o]
+        if t == "TopK" and len(node.outputs) > 1:
+            dt[node.outputs[1]] = "int64"
     return dt
 
 
@@ -300,6 +304,17 @@ def _node_shape(node: Node, sh, const) -> Shape:  # noqa: C901 (dispatch table)
         )
     if t == "GlobalAveragePool":
         return None if s0 is None else (s0[0], s0[1], 1, 1)
+    if t == "TopK":
+        k = const(node.inputs[1]) if len(node.inputs) > 1 else None
+        if s0 is None or k is None:
+            return None
+        axis = int(node.attrs.get("axis", -1)) % len(s0)
+        return tuple(int(np.asarray(k).reshape(-1)[0]) if i == axis else d for i, d in enumerate(s0))
+    if t == "OneHot":
+        depth = const(node.inputs[1]) if len(node.inputs) > 1 else None
+        if s0 is None or depth is None:
+            return None
+        return tuple(s0) + (int(np.asarray(depth).reshape(-1)[0]),)
     if t in ("ReduceMean", "ReduceMax", "ReduceSum"):
         if s0 is None:
             return None
@@ -399,8 +414,8 @@ def axis_inputs(graph: Graph, axis: str) -> List[str]:
 #: Ops that are elementwise and shape-preserving along every axis whenever the
 #: dynamic axis rides only the data operand (scales/zero-points are constants).
 _ROWWISE_OPS = frozenset(
-    {"Relu", "Tanh", "Sigmoid", "Erf", "Sqrt", "Clip", "Identity",
-     "Cast", "QuantizeLinear", "DequantizeLinear"}
+    {"Relu", "Tanh", "Sigmoid", "Erf", "Sqrt", "Clip", "Identity", "Round",
+     "Cast", "QuantizeLinear", "DequantizeLinear", "OneHot"}
 )
 #: Contractions whose first operand carries independent rows / the N axis.
 _LEAD0_OPS = frozenset({"MatMul", "MatMulInteger", "Gemm"})
@@ -521,7 +536,13 @@ def axis_mixing_nodes(
                 reason = "axis is the matmul contraction dim"
             elif t in ("MatMul", "MatMulInteger"):
                 s1 = ga.shape(node.inputs[1])
-                if s1 is None or len(s1) != 2:
+                if s1 is None:
+                    reason = "rhs shape unknown"
+                elif len(s1) != 2 and not (
+                    # a stack of constant weights (one per expert) broadcasts
+                    # over lhs dims to the right of the axis, never over it
+                    ga.is_const(node.inputs[1]) and rank is not None and p0 < rank - len(s1)
+                ):
                     reason = "rhs is not a known 2-D operand (stacked matmul may broadcast over the axis)"
         elif t in _NCHW_OPS:
             if not only_data:
@@ -533,6 +554,11 @@ def axis_mixing_nodes(
                 reason = "cannot normalize the softmax axis"
             elif int(node.attrs.get("axis", -1)) % rank == p0:
                 reason = "softmax normalizes over the axis"
+        elif t == "TopK":
+            if not only_data or rank is None or p0 is None:
+                reason = "cannot locate the top-k axis"
+            elif int(node.attrs.get("axis", -1)) % rank == p0:
+                reason = "selects along the axis"
         elif t in ("ReduceMean", "ReduceMax", "ReduceSum"):
             axes = node.attrs.get("axes")
             if axes is None or rank is None or p0 is None:

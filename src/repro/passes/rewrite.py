@@ -177,6 +177,116 @@ def _record(m: Match, spec: OpSpec, node: Node, consts: Dict[str, np.ndarray]) -
     m.consts.update(consts)
 
 
+@dataclasses.dataclass(frozen=True)
+class NodeSpec:
+    """One node of a :class:`Region`.
+
+    name    capture name of the node
+    op      accepted op_type
+    inputs  one reference per input, in order: ``"@cap"`` the (first)
+            output of the region node captured as ``cap``; ``"$ext"`` a
+            tensor from outside the region, bound by name (the same name
+            twice must be the same tensor); ``"#cst"`` an initializer,
+            captured into ``RegionMatch.consts``
+    attrs   attribute values that must match exactly
+    where   extra predicate on (analysis, node)
+    """
+
+    name: str
+    op: str
+    inputs: Tuple[str, ...]
+    attrs: Mapping[str, Any] = dataclasses.field(default_factory=dict)
+    where: Optional[Predicate] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Region:
+    """A DAG of operators matched as data: :class:`NodeSpec` entries whose
+    inputs name each other, the last one the region's sink.  Where
+    :class:`Pattern` follows one single-consumer chain, a region may fan out
+    (a tensor read by several region nodes) and take several tensors from
+    outside."""
+
+    name: str
+    nodes: Tuple[NodeSpec, ...]
+
+    @property
+    def sink(self) -> NodeSpec:
+        return self.nodes[-1]
+
+
+@dataclasses.dataclass
+class RegionMatch:
+    """The region's nodes by capture name, its outside tensors by ``$``
+    name and its initializer values by ``#`` name."""
+
+    region: Region
+    nodes: Dict[str, Node]
+    inputs: Dict[str, str]
+    consts: Dict[str, np.ndarray]
+
+    @property
+    def sink(self) -> Node:
+        return self.nodes[self.region.sink.name]
+
+    @property
+    def out_tensor(self) -> str:
+        return self.sink.outputs[0]
+
+    def members(self) -> List[Node]:
+        """The matched nodes in the region's declared order."""
+        return [self.nodes[spec.name] for spec in self.region.nodes]
+
+
+def match_region(ga: GraphAnalysis, sink: Node, region: Region) -> Optional[RegionMatch]:
+    """Match ``region`` with its sink at ``sink``, walking producers back
+    from it.  Every spec must bind, and every tensor produced inside the
+    region but the sink's must be read only by region nodes and must not be
+    a graph output, so that fusing the region orphans nothing."""
+    specs = {spec.name: spec for spec in region.nodes}
+    m = RegionMatch(region, {}, {}, {})
+
+    def bind(name: str, node: Node) -> bool:
+        spec = specs[name]
+        if name in m.nodes:
+            return m.nodes[name] is node
+        if node.op_type != spec.op or len(node.inputs) != len(spec.inputs):
+            return False
+        if any(node.attrs.get(k) != v for k, v in spec.attrs.items()):
+            return False
+        if spec.where is not None and not spec.where(ga, node):
+            return False
+        m.nodes[name] = node
+        for ref, tensor in zip(spec.inputs, node.inputs):
+            kind, key = ref[0], ref[1:]
+            if kind == "@":
+                producer = ga.producers.get(tensor)
+                if producer is None or producer.outputs[0] != tensor or not bind(key, producer):
+                    return False
+            elif kind == "$":
+                if ga.is_const(tensor) or m.inputs.setdefault(key, tensor) != tensor:
+                    return False
+            elif kind == "#":
+                value = ga.const(tensor)
+                if value is None:
+                    return False
+                m.consts[key] = value
+            else:
+                raise ValueError(f"bad input reference {ref!r} in region {region.name!r}")
+        return True
+
+    if not bind(region.sink.name, sink) or len(m.nodes) != len(specs):
+        return None
+    inside = {id(n) for n in m.nodes.values()}
+    for node in m.nodes.values():
+        if node is sink:
+            continue
+        for out in node.outputs:
+            if out in ga.out_names or any(id(c) not in inside for c in ga.consumers.get(out, [])):
+                return None
+    return m
+
+
 def ql_params(ga: GraphAnalysis, node: Node):
     """(scale, zero_point) initializers of a QuantizeLinear/DequantizeLinear
     node; zero_point defaults to int8 0.  None scale means non-constant."""

@@ -49,13 +49,23 @@ from ..core.patterns import (
     ATTN_BIG,
     ATTN_LUT_SCALE,
     ATTN_P_SCALE,
+    ROPE_FRAC_BITS,
     build_exp_lut,
+    emit_moe_experts,
     emit_qattention,
+    emit_rmsnorm,
+    emit_rope,
+    emit_rope_tables,
     emit_round_clip,
+    emit_router,
+    emit_swiglu,
     fc_layer,
+    fc_layer_f32,
+    rope_tables,
 )
 from ..core.quant import QuantizedLinearParams, quantize_linear_layer
 from ..kernels import ref as _ref
+from ..kernels.ref import MOE_FIXED
 from ..obs import trace as _trace
 from ..obs.metrics import default_registry
 
@@ -93,10 +103,31 @@ class TokenPathConfig:
     bits_o: int = 8
     bits_up: int = 8
     bits_down: int = 4
+    #: ``"toy"``: the codified block above (MHA, ReLU MLP, no norm or
+    #: positions).  ``"moe"``: a sparse-expert decoder block (Qwen2-MoE):
+    #: RMSNorm, rotary positions, ``n_experts`` routed SwiGLU experts of width
+    #: ``d_expert`` with top-``top_k`` routing, and a sigmoid-gated shared
+    #: SwiGLU expert of width ``d_ff``; positions become a graph input.
+    block: str = "toy"
+    n_experts: int = 0
+    top_k: int = 0
+    d_expert: int = 0
+    bits_expert_down: int = 4
+    rms_eps: float = 1e-6
+    rope_theta: float = 1e6
+    max_pos: int = 1024
+    #: Scale of the SwiGLU products (routed and shared): ``silu(g)·u`` is
+    #: quadratic in the activations and gets its own int8 scale.
+    glu_scale: float = 0.025
 
     @property
     def d_head(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def positions(self) -> bool:
+        """Whether the graphs take token positions (rotary blocks do)."""
+        return self.block != "toy"
 
     @property
     def qk_scale(self) -> float:
@@ -110,12 +141,36 @@ class TokenPathConfig:
 
 @dataclasses.dataclass
 class TokenPathParams:
-    """Pre-quantized parameters of the token path (what the artifact embeds)."""
+    """Pre-quantized parameters of the token path (what the artifact embeds).
+
+    A ``"moe"`` layer's dict holds ``qkv``/``o`` and the shared expert's
+    ``shared_gate``/``shared_up``/``shared_down``/``shared_router`` as
+    :class:`QuantizedLinearParams`, the norm gains ``attn_norm``/``ffn_norm``
+    (γ, f32 ``(D,)``) and the routed experts as :class:`RoutedExperts`."""
 
     embedding: np.ndarray  # (vocab, d_model) int8 codes; row 0 all-zero
-    layers: List[Dict[str, QuantizedLinearParams]]
+    layers: List[Dict[str, object]]
     lm_head: np.ndarray  # (d_model, vocab) int8
     lm_scale: float
+    final_norm: Optional[np.ndarray] = None  # γ of the last RMSNorm ("moe")
+
+
+@dataclasses.dataclass
+class RoutedExperts:
+    """One sparse-expert layer's router and stacked expert weights.
+
+    The router's int32 logits times ``router_scale`` are its f32 logits; each
+    expert projection has one f32 rescale across the experts."""
+
+    router: np.ndarray  # (D, E) int8
+    router_scale: float
+    gate: np.ndarray  # (E, D, F) int8
+    up: np.ndarray  # (E, D, F) int8
+    down: np.ndarray  # (E, F, D) int8 container; int4 values when bits_down == 4
+    r_gate: float
+    r_up: float
+    r_down: float
+    bits_down: int = 4
 
 
 def make_token_params(cfg: TokenPathConfig, seed: int = 0) -> TokenPathParams:
@@ -123,6 +178,8 @@ def make_token_params(cfg: TokenPathConfig, seed: int = 0) -> TokenPathParams:
     that activations stay inside int8 on typical inputs (bit-exactness never
     depends on this — saturation is itself exact — it just keeps the logits
     informative)."""
+    if cfg.block == "moe":
+        return _make_moe_params(cfg, seed)
     rng = np.random.default_rng(seed)
     emb = rng.integers(-40, 41, (cfg.vocab, cfg.d_model)).astype(np.int8)
     emb[0] = 0  # token 0 doubles as padding: zero embedding
@@ -145,6 +202,56 @@ def make_token_params(cfg: TokenPathConfig, seed: int = 0) -> TokenPathParams:
         )
     head = rng.integers(-64, 65, (cfg.d_model, cfg.vocab)).astype(np.int8)
     return TokenPathParams(emb, layers, head, cfg.lm_scale)
+
+
+def _make_moe_params(cfg: TokenPathConfig, seed: int) -> TokenPathParams:
+    """Seeded parameters of the sparse-expert block: per-tensor weight
+    scales (one across all experts of a projection), γ near 1."""
+    rng = np.random.default_rng(seed)
+    s, glu = cfg.act_scale, cfg.glu_scale
+    D, E, F = cfg.d_model, cfg.n_experts, cfg.d_expert
+    emb = rng.integers(-40, 41, (cfg.vocab, D)).astype(np.int8)
+    emb[0] = 0
+
+    def lin(n_in, n_out, bits, *, bias=False, s_in=s, s_out=s):
+        w = rng.normal(size=(n_in, n_out)).astype(np.float32) * (0.6 / np.sqrt(n_in))
+        b = rng.normal(size=(n_out,)).astype(np.float32) * 0.02 if bias else None
+        return quantize_linear_layer(w, b, s_in, s_out, bits=bits)
+
+    def stack(shape, bits):
+        w = rng.normal(size=shape).astype(np.float32) * (0.6 / np.sqrt(shape[1]))
+        qmax = 7 if bits == 4 else 127
+        scale = float(np.abs(w).max()) / qmax
+        return np.clip(np.rint(w / scale), -qmax, qmax).astype(np.int8), scale
+
+    def gain():
+        return (1.0 + 0.1 * rng.normal(size=(D,))).astype(np.float32)
+
+    layers = []
+    for _ in range(cfg.n_layers):
+        router, sr = stack((1, D, E), 8)
+        gate, sg = stack((E, D, F), 8)
+        up, su = stack((E, D, F), 8)
+        down, sd = stack((E, F, D), cfg.bits_expert_down)
+        layers.append({
+            "attn_norm": gain(),
+            "qkv": lin(D, 3 * D, cfg.bits_qkv, bias=True),
+            "o": lin(D, D, cfg.bits_o),
+            "ffn_norm": gain(),
+            "experts": RoutedExperts(
+                # four times the router codes' scale: logits of std about 2, a decisive top-k
+                router=router[0], router_scale=float(np.float32(s * sr * 4.0)),
+                gate=gate, up=up, down=down,
+                r_gate=float(np.float32(sg)), r_up=float(np.float32(su)),
+                r_down=float(np.float32(glu * sd / s)), bits_down=cfg.bits_expert_down,
+            ),
+            "shared_gate": lin(D, cfg.d_ff, cfg.bits_up),
+            "shared_up": lin(D, cfg.d_ff, cfg.bits_up),
+            "shared_down": lin(cfg.d_ff, D, cfg.bits_down, s_in=glu),
+            "shared_router": lin(D, 1, 8, s_out=1.0),
+        })
+    head = rng.integers(-64, 65, (D, cfg.vocab)).astype(np.int8)
+    return TokenPathParams(emb, layers, head, cfg.lm_scale, final_norm=gain())
 
 
 # ---------------------------------------------------------------------------
@@ -227,6 +334,8 @@ def build_prefill_model(cfg: TokenPathConfig, params: TokenPathParams) -> pqir.M
     ``("N","S",D) int8`` per layer, in the same (k, v) × layer order as the
     decode graph's declared states — :class:`CompiledTokenPath` zips the two,
     so a prefilled cache feeds decode directly."""
+    if cfg.block == "moe":
+        return _build_moe_model(cfg, params, decode=False)
     D, V = cfg.d_model, cfg.vocab
     gb = pqir.GraphBuilder("token_prefill")
     gb.add_input("tokens", "int32", ("N", "S"))
@@ -263,6 +372,8 @@ def build_decode_model(cfg: TokenPathConfig, params: TokenPathParams) -> pqir.Mo
     plus per-layer state inputs ``k_cache_l`` / ``v_cache_l ("N","S",D)``.
     Each state's updated tensor is both a graph output and a declared
     :class:`~repro.core.pqir.StateSpec`, so the lowering pins its buffers."""
+    if cfg.block == "moe":
+        return _build_moe_model(cfg, params, decode=True)
     D, V = cfg.d_model, cfg.vocab
     gb = pqir.GraphBuilder("token_decode")
     gb.add_input("tokens", "int32", ("N", 1))
@@ -294,6 +405,91 @@ def build_decode_model(cfg: TokenPathConfig, params: TokenPathParams) -> pqir.Mo
         gb.add_output(v_upd, "int8", ("N", "S", D))
         gb.add_state(f"kv{l}_k", input=f"k_cache_{l}", output=k_upd)
         gb.add_state(f"kv{l}_v", input=f"v_cache_{l}", output=v_upd)
+    return gb.build(opset=17)
+
+
+def _moe_block(gb, cfg: TokenPathConfig, p: Dict[str, object], x: str, rope, attend, pfx: str):
+    """One sparse-expert decoder layer on int8 codes ``x``: pre-norm
+    attention with rotary q/k, then pre-norm routed + shared experts.
+    ``attend(q, k, v)`` returns ``(ctx, k_state, v_state)``.  Returns the
+    layer's output, its K/V states and the router's chosen experts."""
+    s, D = cfg.act_scale, cfg.d_model
+    eps = cfg.rms_eps / (s * s)
+    cos, sin, perm = rope
+    xn = emit_rmsnorm(gb, x, p["attn_norm"] / np.float32(s), eps, f"{pfx}_ln1")
+    qkv = fc_layer(gb, xn, p["qkv"], f"{pfx}_qkv")
+    q = emit_rope(gb, _slice_feat(gb, qkv, 0, D, f"{pfx}_qs"), cos, sin, perm, f"{pfx}_qrope")
+    k = emit_rope(gb, _slice_feat(gb, qkv, D, 2 * D, f"{pfx}_ks"), cos, sin, perm, f"{pfx}_krope")
+    ctx, k_st, v_st = attend(q, k, _slice_feat(gb, qkv, 2 * D, 3 * D, f"{pfx}_vs"))
+    x1 = _residual(gb, x, fc_layer(gb, ctx, p["o"], f"{pfx}_o"), f"{pfx}_res1")
+    h = emit_rmsnorm(gb, x1, p["ffn_norm"] / np.float32(s), eps, f"{pfx}_ln2")
+    ex: RoutedExperts = p["experts"]
+    idx, probs = emit_router(gb, h, ex.router, ex.router_scale, cfg.top_k, f"{pfx}_router")
+    routed = emit_moe_experts(
+        gb, h, idx, probs, ex.gate, ex.up, ex.down, f"{pfx}_moe",
+        r_g=ex.r_gate, s_g=s, r_u=ex.r_up, r_h=s / cfg.glu_scale, r_d=ex.r_down,
+        bits_down=ex.bits_down,
+    )
+    g = fc_layer(gb, h, p["shared_gate"], f"{pfx}_sh_gate")
+    u = fc_layer(gb, h, p["shared_up"], f"{pfx}_sh_up")
+    hs = emit_swiglu(gb, g, u, s, s / cfg.glu_scale, f"{pfx}_sh_glu")
+    ys = fc_layer_f32(gb, hs, p["shared_down"], f"{pfx}_sh_down")
+    gate = gb.op("Sigmoid", [fc_layer_f32(gb, h, p["shared_router"], f"{pfx}_sh_router")], out_hint=f"{pfx}_sh_sig")
+    shared = gb.op("Mul", [ys, gate], out_hint=f"{pfx}_sh_out")
+    rf = gb.op("Cast", [routed], out_hint=f"{pfx}_routed_f", to="float32")
+    unit = gb.add_initializer(f"{pfx}_fixed_unit", np.float32(1.0 / MOE_FIXED))
+    total = gb.op("Add", [gb.op("Mul", [rf, unit], out_hint=f"{pfx}_routed"), shared], out_hint=f"{pfx}_ffn")
+    out = emit_round_clip(gb, total, f"{pfx}_ffn")
+    return _residual(gb, x1, out, f"{pfx}_res2"), k_st, v_st, idx
+
+
+def _build_moe_model(cfg: TokenPathConfig, params: TokenPathParams, *, decode: bool) -> pqir.Model:
+    """The sparse-expert block's prefill or decode artifact.  As the toy
+    graphs, plus ``positions`` (``("N","S")`` / ``("N",1)`` int32) in, and
+    each layer's chosen experts ``("N","S",K)`` int32 out after the K/V
+    outputs."""
+    D, V, K = cfg.d_model, cfg.vocab, cfg.top_k
+    s_axis = 1 if decode else "S"
+    gb = pqir.GraphBuilder("token_decode" if decode else "token_prefill")
+    gb.add_input("tokens", "int32", ("N", s_axis))
+    gb.add_input("positions", "int32", ("N", s_axis))
+    if decode:
+        gb.add_input("onehot", "int8", ("N", "S", 1))
+        gb.add_input("mask", "float32", ("N", 1, "S"))
+        for l in range(cfg.n_layers):
+            gb.add_input(f"k_cache_{l}", "int8", ("N", "S", D))
+            gb.add_input(f"v_cache_{l}", "int8", ("N", "S", D))
+    else:
+        gb.add_input("mask", "float32", ("N", "S", "S"))
+    cos_t, sin_t, perm = rope_tables(cfg.max_pos, cfg.n_heads, cfg.d_head, cfg.rope_theta)
+    cos, sin = emit_rope_tables(gb, "positions", cos_t, sin_t, "rope")
+    table = gb.add_initializer("embedding_q", params.embedding)
+    x = gb.op("Gather", [table, "tokens"], out_hint="emb", axis=0)
+    states, experts = [], []
+    for l, p in enumerate(params.layers):
+        pfx = f"l{l}"
+
+        def attend(q, k, v, l=l, pfx=pfx):
+            if decode:
+                k = _kv_update(gb, f"k_cache_{l}", k, "onehot", f"{pfx}_kupd")
+                v = _kv_update(gb, f"v_cache_{l}", v, "onehot", f"{pfx}_vupd")
+            return _attention(gb, cfg, q, k, v, "mask", pfx), k, v
+
+        x, k_st, v_st, idx = _moe_block(gb, cfg, p, x, (cos, sin, perm), attend, pfx)
+        states.append((k_st, v_st))
+        experts.append(idx)
+    xn = emit_rmsnorm(gb, x, params.final_norm / np.float32(cfg.act_scale),
+                      cfg.rms_eps / cfg.act_scale**2, "final_norm")
+    gb.add_output(_lm_head(gb, cfg, params, xn), "float32", ("N", s_axis, V))
+    for l, (k_st, v_st) in enumerate(states):
+        seq = "S"
+        gb.add_output(k_st, "int8", ("N", seq, D))
+        gb.add_output(v_st, "int8", ("N", seq, D))
+        if decode:
+            gb.add_state(f"kv{l}_k", input=f"k_cache_{l}", output=k_st)
+            gb.add_state(f"kv{l}_v", input=f"v_cache_{l}", output=v_st)
+    for idx in experts:
+        gb.add_output(idx, "int32", ("N", s_axis, K))
     return gb.build(opset=17)
 
 
@@ -347,11 +543,93 @@ def _logits_jax(params: TokenPathParams, x):
     return acc.astype(jnp.float32) * jnp.float32(params.lm_scale)
 
 
-def prefill_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, mask, lut=None):
+def _round_clip_jax(f):
+    return jnp.clip(jnp.rint(f), -128, 127).astype(jnp.int8)
+
+
+def _rmsnorm_jax(x, gamma, cfg: TokenPathConfig):
+    return _ref.rmsnorm_ref(
+        x, jnp.asarray(gamma / np.float32(cfg.act_scale)), np.float32(1.0 / x.shape[-1]),
+        np.float32(cfg.rms_eps / cfg.act_scale**2),
+    )
+
+
+def _rope_jax(x, cos, sin, perm):
+    xi = x.astype(jnp.int32)
+    f = (xi * cos + jnp.take(xi, perm, axis=2) * sin).astype(jnp.float32)
+    return _round_clip_jax(f * np.float32(2.0 ** -ROPE_FRAC_BITS))
+
+
+def _fc_f32_jax(x_q, p: QuantizedLinearParams):
+    return _ref.qmatmul_ref(
+        jnp.asarray(x_q), jnp.asarray(p.weight_q), None,
+        jnp.float32(p.rescale.multiplier), jnp.float32(1.0), out_dtype=jnp.float32, two_mul=False,
+    )
+
+
+def _swiglu_jax(g, u, s_g, r_h):
+    gx = g.astype(jnp.float32) * np.float32(s_g)
+    return _round_clip_jax(((gx * jax.nn.sigmoid(gx)) * u.astype(jnp.float32)) * np.float32(r_h))
+
+
+def _moe_ffn_jax(cfg: TokenPathConfig, p, h):
+    """Router, routed experts (the ``qmoe`` oracle) and the gated shared
+    expert of one layer; returns (int8 output, chosen experts)."""
+    s, r_h = cfg.act_scale, cfg.act_scale / cfg.glu_scale
+    ex: RoutedExperts = p["experts"]
+    acc = jax.lax.dot_general(
+        h.astype(jnp.int32), jnp.asarray(ex.router, jnp.int32), (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )
+    idx = jax.lax.top_k(acc, cfg.top_k)[1]
+    probs = _ref.softmax_rn(acc.astype(jnp.float32) * np.float32(ex.router_scale))
+    n, t, d = h.shape
+    routed = _ref.qmoe_ref(
+        h.reshape(n * t, d), idx.reshape(n * t, -1), probs.reshape(n * t, -1),
+        jnp.asarray(ex.gate), jnp.asarray(ex.up), jnp.asarray(ex.down),
+        r_g=np.float32(ex.r_gate), s_g=np.float32(s), r_u=np.float32(ex.r_up),
+        r_h=np.float32(r_h), r_d=np.float32(ex.r_down),
+    ).reshape(n, t, d)
+    hs = _swiglu_jax(_fc_jax(h, p["shared_gate"]), _fc_jax(h, p["shared_up"]), s, r_h)
+    shared = _fc_f32_jax(hs, p["shared_down"]) * jax.nn.sigmoid(_fc_f32_jax(h, p["shared_router"]))
+    total = routed.astype(jnp.float32) * np.float32(1.0 / MOE_FIXED) + shared
+    return _round_clip_jax(total), idx
+
+
+def _moe_forward_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, positions, mask, lut, kv):
+    """jnp mirror of a sparse-expert artifact; ``kv(l, k, v)`` returns the
+    K/V that attention reads (prefill: the rows; decode: the updated
+    cache).  Returns (logits, [(k, v)], [chosen experts]) per layer."""
+    D = cfg.d_model
+    cos_t, sin_t, perm = rope_tables(cfg.max_pos, cfg.n_heads, cfg.d_head, cfg.rope_theta)
+    pos = jnp.asarray(positions, jnp.int32)
+    cos = jnp.take(jnp.asarray(cos_t), pos, axis=0).astype(jnp.int32)
+    sin = jnp.take(jnp.asarray(sin_t), pos, axis=0).astype(jnp.int32)
+    perm = jnp.asarray(perm, jnp.int32)
+    x = jnp.take(jnp.asarray(params.embedding), jnp.asarray(tokens, jnp.int32), axis=0)
+    states, experts = [], []
+    for l, p in enumerate(params.layers):
+        qkv = _fc_jax(_rmsnorm_jax(x, p["attn_norm"], cfg), p["qkv"])
+        q = _rope_jax(qkv[..., :D], cos, sin, perm)
+        k, v = kv(l, _rope_jax(qkv[..., D : 2 * D], cos, sin, perm), qkv[..., 2 * D :])
+        states.append((k, v))
+        x1 = _residual_jax(x, _fc_jax(_attention_jax(cfg, q, k, v, mask, lut), p["o"]))
+        out, idx = _moe_ffn_jax(cfg, p, _rmsnorm_jax(x1, p["ffn_norm"], cfg))
+        experts.append(idx)
+        x = _residual_jax(x1, out)
+    return _logits_jax(params, _rmsnorm_jax(x, params.final_norm, cfg)), states, experts
+
+
+def prefill_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, mask, lut=None, positions=None):
     """jnp mirror of the prefill artifact: op-for-op the same integer/f32
     chain, so the result is bit-identical.  Returns (logits, [(k, v)] per
-    layer)."""
+    layer).  A rotary block also needs ``positions`` ``(N, S)``."""
     lut = build_exp_lut() if lut is None else lut
+    if cfg.block == "moe":
+        logits, states, _ = _moe_forward_jax(
+            cfg, params, tokens, positions, mask, lut, lambda l, k, v: (k, v)
+        )
+        return logits, states
     D = cfg.d_model
     x = jnp.take(jnp.asarray(params.embedding), jnp.asarray(tokens, jnp.int32), axis=0)
     caches = []
@@ -363,13 +641,23 @@ def prefill_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, mask, lut
     return _logits_jax(params, x), caches
 
 
-def decode_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, onehot, mask, states, lut=None):
+def decode_jax(cfg: TokenPathConfig, params: TokenPathParams, tokens, onehot, mask, states, lut=None,
+               positions=None):
     """jnp mirror of the decode artifact.  ``states`` is [(k, v)] per layer;
-    returns (logits, new_states) with the codified int8 scatter update."""
+    returns (logits, new_states) with the codified int8 scatter update.  A
+    rotary block also needs ``positions`` ``(N, 1)``."""
     lut = build_exp_lut() if lut is None else lut
     D = cfg.d_model
     oh = jnp.asarray(onehot, jnp.int8)
     keep = (jnp.int8(1) - oh).astype(jnp.int8)
+    if cfg.block == "moe":
+        def kv(l, kn, vn):
+            k_st, v_st = states[l]
+            return ((jnp.asarray(k_st) * keep + kn * oh).astype(jnp.int8),
+                    (jnp.asarray(v_st) * keep + vn * oh).astype(jnp.int8))
+
+        logits, new_states, _ = _moe_forward_jax(cfg, params, tokens, positions, mask, lut, kv)
+        return logits, new_states
     x = jnp.take(jnp.asarray(params.embedding), jnp.asarray(tokens, jnp.int32), axis=0)
     new_states = []
     for p, (k_st, v_st) in zip(params.layers, states):
@@ -424,27 +712,85 @@ class CompiledTokenPath:
         # prefill outputs [1:] are the per-layer (k, v) rows in state order
         pre_kv = [t.name for t in self.prefill_model.graph.outputs[1:]]
         self._prefill_kv = {s.input: n for s, n in zip(self.state_specs, pre_kv)}
+        # a sparse-expert block's graphs end with each layer's chosen experts
+        n_kv = 1 + len(self.state_specs)
+        self._experts_prefill = [t.name for t in self.prefill_model.graph.outputs[n_kv:]]
+        self._experts_decode = [t.name for t in self.decode_model.graph.outputs[n_kv:]]
+        #: chosen experts of the last prefill (host) or decode (device) call,
+        #: ``(layers, N, S, K)`` int32; None for the toy block
+        self.last_routing = None
+        #: the decode steps' routing counts not counted yet, ``[rows,
+        #: experts_hit, layer_calls]`` int32 on the device (None: nothing),
+        #: and how many steps they hold
+        self._routing_counts = None
+        self._routing_steps = 0
         # jitted one-dispatch decode steps, keyed by exact (N, S) cell
         self._step_fns: Dict[Tuple[int, int], object] = {}
 
     # -- direct run API -------------------------------------------------------
-    def prefill(self, tokens: np.ndarray, mask: np.ndarray):
-        """Returns (logits (N,S,V) f32, {state-input name: (N,S,D) int8})."""
-        outs = self.prefill_cm.run({"tokens": np.asarray(tokens, np.int32), "mask": mask})
+    def prefill(self, tokens: np.ndarray, mask: np.ndarray, positions: Optional[np.ndarray] = None):
+        """Returns (logits (N,S,V) f32, {state-input name: (N,S,D) int8}).
+        A rotary block takes ``positions`` (default ``0..S-1`` per row)."""
+        feeds = {"tokens": np.asarray(tokens, np.int32), "mask": mask}
+        if self.cfg.positions:
+            if positions is None:
+                positions = np.broadcast_to(np.arange(np.shape(tokens)[1]), np.shape(tokens))
+            feeds["positions"] = np.asarray(positions, np.int32)
+        outs = self.prefill_cm.run(feeds)
         cache = {inp: np.asarray(outs[name]) for inp, name in self._prefill_kv.items()}
+        if self._experts_prefill:
+            self.last_routing = np.stack([outs[n] for n in self._experts_prefill])
+            self._count_routing(self.last_routing, decode=False)
         return np.asarray(outs[self._logits_prefill]), cache
 
-    def decode(self, tokens, onehot, mask, cache: Dict[str, np.ndarray]):
-        """One decode step.  Returns (logits (N,1,V), next cache dict)."""
+    def decode(self, tokens, onehot, mask, cache: Dict[str, np.ndarray], positions=None):
+        """One decode step.  Returns (logits (N,1,V), next cache dict).  A
+        rotary block takes ``positions`` ``(N, 1)``."""
         feeds = {
             "tokens": np.asarray(tokens, np.int32),
             "onehot": np.asarray(onehot, np.int8),
             "mask": mask,
         }
+        if self.cfg.positions:
+            feeds["positions"] = np.asarray(positions, np.int32).reshape(-1, 1)
         feeds.update(cache)
         outs = self.decode_cm.run(feeds)
         nxt = {s.input: np.asarray(outs[s.output]) for s in self.state_specs}
+        if self._experts_decode:
+            self.last_routing = np.stack([outs[n] for n in self._experts_decode])
+            self._count_routing(self.last_routing, decode=True)
         return np.asarray(outs[self._logits_decode]), nxt
+
+    # -- routing counters ----------------------------------------------------
+    def _count_routing(self, routing: np.ndarray, *, decode: bool) -> None:
+        """Count one call's routing ``(layers, N, S, K)`` into the registry
+        (see :meth:`_count`)."""
+        layers = routing.shape[0]
+        rows = int(np.prod(routing.shape[1:-1])) * layers
+        hit = sum(int(np.unique(routing[l]).size) for l in range(layers))
+        self._count(rows, hit, layers, decode=decode)
+
+    @staticmethod
+    def _count(rows: int, hit: int, layers: int, *, decode: bool) -> None:
+        """``tokenpath.moe.rows`` (rows routed, per layer), ``.experts_hit``
+        (distinct experts with a row, per layer) and ``.layer_calls``; the
+        decode calls also under ``tokenpath.moe.decode.*``."""
+        with _trace.span("tokenpath.moe.route"):
+            reg = default_registry()
+            for scope in ("tokenpath.moe",) + (("tokenpath.moe.decode",) if decode else ()):
+                reg.counter(f"{scope}.rows").inc(rows)
+                reg.counter(f"{scope}.experts_hit").inc(hit)
+                reg.counter(f"{scope}.layer_calls").inc(layers)
+
+    def flush_routing(self) -> None:
+        """Count the routing of the decode steps not counted yet.  The
+        jitted decode adds each step's counts to one int32 triple on the
+        device, so that an untraced step neither waits for its routing nor
+        keeps it; a tracer flushes after each step."""
+        counts, self._routing_counts, self._routing_steps = self._routing_counts, None, 0
+        if counts is not None:
+            rows, hit, layers = (int(v) for v in np.asarray(counts))
+            self._count(rows, hit, layers, decode=True)
 
     def decode_step(self, tokens, pos, cache):
         """The decode hot loop: one step at *exact* bucket extents, keeping
@@ -477,20 +823,29 @@ class CompiledTokenPath:
             onehot = np.zeros((n, s, 1), np.int8)
             onehot[np.arange(n), np.clip(pos, 0, s - 1), 0] = 1
             mask = (np.arange(s)[None, None, :] <= pos[:, None, None]).astype(np.float32)
-            logits, nxt = self.decode(tokens, onehot, mask, cache)
+            logits, nxt = self.decode(tokens, onehot, mask, cache, positions=pos)
             return logits[:, 0, :], nxt
         plan, _ = cm.specialized({"N": n, "S": s})  # per-step cell accounting
         entry = self._step_fns.get((n, s))
         if entry is None:
             logits_name, specs = self._logits_decode, self.state_specs
+            experts, rotary = self._experts_decode, self.cfg.positions
 
-            def step(params, toks, pos, cache):
+            n_experts = self.cfg.n_experts
+
+            def step(params, toks, pos, cache, counts):
                 onehot = (jnp.arange(s)[None, :, None] == pos[:, None, None]).astype(jnp.int8)
                 mask = (jnp.arange(s)[None, None, :] <= pos[:, None, None]).astype(jnp.float32)
                 feeds = {"tokens": toks, "onehot": onehot, "mask": mask}
+                if rotary:
+                    feeds["positions"] = pos[:, None]
                 feeds.update(cache)
                 outs = plan.execute(feeds, params)
-                return outs[logits_name][:, 0, :], {sp.input: outs[sp.output] for sp in specs}
+                nxt = {sp.input: outs[sp.output] for sp in specs}
+                if experts:
+                    routing = jnp.stack([outs[name] for name in experts])
+                    nxt = (nxt, routing, counts + _routing_counts(routing, n_experts))
+                return outs[logits_name][:, 0, :], nxt
 
             entry = self._step_fns[(n, s)] = (jax.jit(step), plan.params())
         fn, params = entry
@@ -499,12 +854,21 @@ class CompiledTokenPath:
             # the jitted call's dispatch, so that its own span can time it
             with _trace.span("tokenpath.decode.put"):
                 cache = jax.block_until_ready(jax.device_put(cache))
+        counts = self._routing_counts
+        if counts is None and self._experts_decode:
+            counts = np.zeros(3, np.int32)
         with _trace.span("tokenpath.decode.dispatch"):
             logits, nxt = fn(
-                params, jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache
+                params, jnp.asarray(tokens, jnp.int32), jnp.asarray(np.asarray(pos), jnp.int32), cache, counts
             )
+        if self._experts_decode:
+            nxt, self.last_routing, self._routing_counts = nxt
+            self._routing_steps += 1
         with _trace.span("tokenpath.decode.fetch"):
-            return np.asarray(logits), nxt
+            logits = np.asarray(logits)
+        if self._routing_counts is not None and (_trace.enabled or self._routing_steps >= ROUTING_FLUSH_STEPS):
+            self.flush_routing()
+        return logits, nxt
 
     def init_cache(self, n: int, s: int) -> Dict[str, np.ndarray]:
         D = self.cfg.d_model
@@ -512,6 +876,23 @@ class CompiledTokenPath:
 
     def cache_stats(self) -> Dict[str, float]:
         return self.plan_cache.stats
+
+
+#: Untraced decode steps whose routing counts stay on the device before they
+#: are counted: the int32 triple cannot wrap, and the counters lag a long
+#: run by at most this many steps (about half an hour at 30 steps a second).
+ROUTING_FLUSH_STEPS = 1 << 16
+
+
+def _routing_counts(routing: jax.Array, n_experts: int) -> jax.Array:
+    """``[rows, experts_hit, layer_calls]`` int32 of one call's routing
+    ``(layers, N, S, K)``, on the device: the rows routed and the distinct
+    experts with a row, each summed over the layers."""
+    layers = routing.shape[0]
+    flat = routing.reshape(layers, -1)
+    hit = jnp.any(flat[:, :, None] == jnp.arange(n_experts, dtype=flat.dtype), axis=1).sum(dtype=jnp.int32)
+    rows = layers * int(np.prod(routing.shape[1:-1]))
+    return jnp.stack([jnp.int32(rows), hit, jnp.int32(layers)])
 
 
 def _count_host_trip() -> None:
@@ -562,7 +943,7 @@ class CompiledTokenAdapter:
         bucket = padded.shape[1]
         with _trace.span("tokenpath.prefill.mask"):
             mask = self._causal_mask(1, bucket)
-        logits, cache = self.tp.prefill(padded, mask)
+        logits, cache = self.tp.prefill(padded, mask, np.arange(bucket)[None])
         return logits[0, plen - 1], cache
 
     def scatter(self, cache, slot: int, pcache):

@@ -276,6 +276,38 @@ def _case_reduce_sum(rng):
     return _finish(gb, y, "int32"), feeds
 
 
+def _case_round(rng):
+    # halves round to even, as QuantizeLinear does
+    gb = _g("round")
+    x = gb.add_input("x", "float32", (4, 8))
+    y = gb.op("Round", [x])
+    xv = _rngf(rng, (4, 8), 4.0)
+    xv[0, :4] = [0.5, 1.5, -2.5, -0.5]
+    return _finish(gb, y, "float32"), {"x": xv}
+
+
+def _case_top_k(rng):
+    # int32 router logits with ties: equal values keep the lower index first
+    gb = _g("topk")
+    x = gb.add_input("x", "int32", (3, 5, 12))
+    k = gb.add_initializer("k", np.asarray([4], np.int64))
+    vals, idx = gb.fresh("vals"), gb.fresh("idx")
+    gb.add_node("TopK", [x, k], [vals, idx], axis=-1, largest=1, sorted=1)
+    gb.add_output(vals, "int32", (3, 5, 4))
+    y = gb.op("Cast", [idx], to="int32")
+    feeds = {"x": rng.integers(-3, 4, (3, 5, 12)).astype(np.int32)}
+    return _finish(gb, y, "int32"), feeds
+
+
+def _case_one_hot(rng):
+    gb = _g("onehot")
+    idx = gb.add_input("idx", "int32", (3, 4, 2))
+    depth = gb.add_initializer("depth", np.asarray([6], np.int64))
+    values = gb.add_initializer("values", np.asarray([0.0, 1.0], np.float32))
+    y = gb.op("OneHot", [idx, depth, values], axis=-1)
+    return _finish(gb, y, "float32"), {"idx": rng.integers(0, 6, (3, 4, 2)).astype(np.int32)}
+
+
 CASES = {
     "MatMulInteger": _case_matmul_integer,
     "ConvInteger": _case_conv_integer,
@@ -310,6 +342,9 @@ CASES = {
     "ReduceMean": _case_reduce_mean,
     "ReduceMax": _case_reduce_max,
     "ReduceSum": _case_reduce_sum,
+    "Round": _case_round,
+    "TopK": _case_top_k,
+    "OneHot": _case_one_hot,
 }
 
 

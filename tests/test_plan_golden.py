@@ -13,6 +13,7 @@ To update after an *intentional* lowering change:
 
 then review the golden diff like any other code change.
 """
+import functools
 import os
 
 import numpy as np
@@ -247,3 +248,33 @@ def test_quickstart_mlp_int4_provenance_golden():
     text = cm.plan.pretty(verbose=True)
     assert "w4/a8" in text and "specializations: 2" in text
     _check_golden("quickstart_mlp_int4.provenance.txt", text + "\n")
+
+
+@functools.lru_cache(maxsize=None)
+def _toy_token_path_renderings():
+    """The toy token path's emitted graphs (a hash of each graph's JSON) and
+    its prefill/decode plans, template and one bucket each."""
+    import hashlib
+    import json
+
+    from repro.serving.token_path import CompiledTokenPath, TokenPathConfig
+
+    tp = CompiledTokenPath(TokenPathConfig(), backend="interpret", seed=0)
+    lines = []
+    for name, model in (("prefill", tp.prefill_model), ("decode", tp.decode_model)):
+        doc = json.dumps(model.graph.to_json(), sort_keys=True)
+        lines.append(f"{name} graph sha256 {hashlib.sha256(doc.encode()).hexdigest()} nodes {len(model.graph.nodes)}")
+    out = {"token_path_toy.graphs.txt": "\n".join(lines) + "\n"}
+    for name, cm, bind in (("prefill", tp.prefill_cm, {"N": 1, "S": 32}), ("decode", tp.decode_cm, {"N": 2, "S": 64})):
+        out[f"token_path_toy.{name}.plan.txt"] = cm.plan.pretty() + "\n" + cm.specialized(bind)[0].pretty() + "\n"
+    return out
+
+
+@pytest.mark.parametrize("name", [
+    "token_path_toy.graphs.txt", "token_path_toy.prefill.plan.txt", "token_path_toy.decode.plan.txt",
+])
+def test_toy_token_path_is_unchanged_by_the_block_builders(name):
+    """The toy block (the benchmark's ouro/MiniCPM cells) emits the same
+    graphs and lowers to the same plans as before the sparse-expert block
+    was added: the goldens were rendered by the commit before it."""
+    _check_golden(name, _toy_token_path_renderings()[name])

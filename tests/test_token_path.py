@@ -30,6 +30,7 @@ from repro.core.patterns import build_exp_lut, emit_qattention
 from repro.core.runtime import ReferenceRuntime
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import default_registry
+from repro.serving import token_path as token_path_mod
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 from repro.serving.token_path import (
     CompiledTokenAdapter,
@@ -419,6 +420,140 @@ class TestTokenPathSpans:
         wait = eng.registry.histogram("engine.queue_wait_ms")
         assert wait.count == len(reqs)
         assert wait.max == pytest.approx(max(1e3 * (r.t_admit - r.t_submit) for r in reqs))
+
+
+class TestMoeRoutingCounters:
+    """The sparse-expert block's routing counters (``tokenpath.moe.*``) and
+    span, served through the engine.  Zero router weights make every router
+    logit 0, so every row of every layer takes experts 0 and 1 (equal logits
+    keep the lower index): a known routing."""
+
+    MOE = TokenPathConfig(vocab=96, d_model=64, n_heads=4, d_ff=96, n_layers=2, block="moe",
+                          n_experts=8, top_k=2, d_expert=32, max_pos=64)
+    NAMES = ("tokenpath.moe.rows", "tokenpath.moe.experts_hit", "tokenpath.moe.layer_calls",
+             "tokenpath.moe.decode.rows", "tokenpath.moe.decode.experts_hit",
+             "tokenpath.moe.decode.layer_calls")
+
+    @classmethod
+    def _tp(cls):
+        params = make_token_params(cls.MOE, seed=4)
+        for layer in params.layers:
+            layer["experts"].router[:] = 0
+        return CompiledTokenPath(cls.MOE, params, backend="ref", s_granularity=8)
+
+    @classmethod
+    def _counts(cls):
+        return {n: default_registry().counter(n).value for n in cls.NAMES}
+
+    @classmethod
+    def _serve(cls, tp, tracer=None, check=None):
+        eng = ServeEngine(ecfg=EngineConfig(slots=2, max_len=16, prefill_bucket=8),
+                          adapter=CompiledTokenAdapter(tp))
+        reqs = [Request(uid=i, prompt=np.arange(1, 4 + i, dtype=np.int32), max_new_tokens=3 + i)
+                for i in range(3)]
+        checked = []
+        if tracer is not None:
+            obs_trace.install(tracer)
+        try:
+            for r in reqs:
+                eng.submit(r)
+            while eng.queue or eng.active:
+                eng.step()
+                if check is not None:
+                    checked.append(check())
+        finally:
+            if tracer is not None:
+                obs_trace.uninstall()
+        return eng, reqs, checked
+
+    def test_counters_count_rows_and_experts_of_a_known_routing(self):
+        tp = self._tp()
+        before = self._counts()
+        eng, _, _ = self._serve(tp)
+        tp.flush_routing()
+        got = {n: v - before[n] for n, v in self._counts().items()}
+        layers, steps, prefills = self.MOE.n_layers, eng.metrics["decode_steps"], eng.metrics["prefills"]
+        assert got["tokenpath.moe.decode.layer_calls"] == layers * steps
+        assert got["tokenpath.moe.decode.rows"] == layers * steps * 2  # every slot's row
+        assert got["tokenpath.moe.decode.experts_hit"] == layers * steps * 2  # experts 0 and 1
+        assert got["tokenpath.moe.layer_calls"] == layers * (steps + prefills)
+        assert got["tokenpath.moe.rows"] == layers * (steps * 2 + prefills * 8)  # a prefill routes its bucket
+        assert got["tokenpath.moe.experts_hit"] == 2 * got["tokenpath.moe.layer_calls"]
+        assert tp.last_routing.shape == (layers, 2, 1, self.MOE.top_k)
+        assert set(np.unique(np.asarray(tp.last_routing)).tolist()) == {0, 1}
+
+    def test_untraced_decode_leaves_the_routing_on_the_device(self, monkeypatch):
+        """No tracer: nothing waits on the device, and the decode routing is
+        not fetched (its counters stand still) until it is flushed."""
+        tp = self._tp()
+        calls = []
+        for name in ("block_until_ready", "device_put"):
+            real = getattr(jax, name)
+            monkeypatch.setattr(jax, name, lambda *a, _real=real, _name=name, **k: (
+                calls.append(_name), _real(*a, **k))[1])
+        before = self._counts()
+        eng, _, _ = self._serve(tp)
+        assert calls == ["device_put"] * (1 + 3)  # the zero cache once, each admission's rows
+        assert self._counts()["tokenpath.moe.decode.layer_calls"] == before["tokenpath.moe.decode.layer_calls"]
+        assert isinstance(tp.last_routing, jax.Array)
+        tp.flush_routing()
+        assert (self._counts()["tokenpath.moe.decode.layer_calls"]
+                == before["tokenpath.moe.decode.layer_calls"] + self.MOE.n_layers * eng.metrics["decode_steps"])
+
+    def test_untraced_steps_keep_one_count_not_one_array_per_step(self):
+        """A long untraced run holds the same device buffers after 40 more
+        steps: the routing is summed into one int32 triple on the device,
+        not kept per step, and the flush counts every step."""
+        tp = self._tp()
+        layers = self.MOE.n_layers
+        toks, pos = np.ones((2, 1), np.int32), np.array([3, 5])
+        cache = jax.device_put(tp.init_cache(2, 16))
+        before = self._counts()["tokenpath.moe.decode.layer_calls"]
+
+        def run(steps, cache):
+            for _ in range(steps):
+                _, cache = tp.decode_step(toks, pos, cache)
+            return cache
+
+        cache = run(5, cache)
+        jax.block_until_ready(cache)
+        held = len(jax.live_arrays())
+        cache = run(40, cache)
+        jax.block_until_ready(cache)
+        assert len(jax.live_arrays()) == held
+        assert tp._routing_counts.shape == (3,)
+        tp.flush_routing()
+        assert tp._routing_counts is None
+        assert self._counts()["tokenpath.moe.decode.layer_calls"] == before + 45 * layers
+
+    def test_untraced_counts_are_flushed_every_so_many_steps(self, monkeypatch):
+        """Without a tracer or a flush, the counts are taken every
+        ``ROUTING_FLUSH_STEPS`` decode steps and held on the device between."""
+        monkeypatch.setattr(token_path_mod, "ROUTING_FLUSH_STEPS", 4)
+        tp = self._tp()
+        layers = self.MOE.n_layers
+        toks, pos = np.ones((2, 1), np.int32), np.array([3, 5])
+        cache = jax.device_put(tp.init_cache(2, 16))
+        calls = default_registry().counter("tokenpath.moe.decode.layer_calls")
+        before, seen = calls.value, []
+        for _ in range(9):
+            _, cache = tp.decode_step(toks, pos, cache)
+            seen.append(calls.value - before)
+        assert seen == [0, 0, 0, 4 * layers, 4 * layers, 4 * layers, 4 * layers, 8 * layers, 8 * layers]
+
+    def test_a_tracer_counts_each_step_and_tokens_stay_bit_equal(self):
+        tp = self._tp()
+        _, plain, _ = self._serve(tp)
+        tp.flush_routing()
+        tracer = obs_trace.Tracer()
+        calls = default_registry().counter("tokenpath.moe.decode.layer_calls")
+        seen = []
+        eng, traced, _ = self._serve(tp, tracer, check=lambda: seen.append(calls.value))
+        # counted as each traced step ends: one count per layer per decode
+        assert seen[-1] - seen[0] == self.MOE.n_layers * (eng.metrics["decode_steps"] - 1)
+        assert [r.generated for r in plain] == [r.generated for r in traced]
+        routes = tracer.spans("tokenpath.moe.route")
+        assert len(routes) == eng.metrics["decode_steps"] + eng.metrics["prefills"]
 
 
 class TestDeviceScatter:

@@ -4,7 +4,8 @@ Nothing runs: the TPU compiler, which is installed beside JAX, compiles for
 a chip that is described and not attached, and refuses what Mosaic cannot
 lower.  Shapes are those of ``chip_smoke.py``'s phase A (the token path's
 block at Ouro-2.6B widths: hidden 2048, FFN 5632, head dim 128, 4 decode
-slots, cache length 1024).
+slots, cache length 1024), and for the grouped expert kernel those of
+Qwen1.5-MoE-A2.7B (60 experts of 2048 -> 1408 -> 2048, top 4).
 """
 import os
 import sys
@@ -17,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import qact_lut as qact
 from repro.kernels import qattention as qatt
 from repro.kernels import qmatmul as qmm
+from repro.kernels import qmoe
 from repro.serving.token_path import CompiledTokenPath, TokenPathConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -82,6 +84,21 @@ def test_qattention(spec, b, s):
         spec((b, s, T), jnp.float32), spec((256,), jnp.uint8),
     )
     assert "tpu_custom_call" in text
+
+
+def test_qmoe(spec):
+    """One decode step's routed experts: 32 rows, 4 experts each, w8
+    gate/up and packed-int4 down; the Pallas call is named ``qmoe``."""
+    e, f, rows, k = 60, 1408, 32, 4
+    text = _compiled_text(
+        lambda x, idx, probs, wg, wu, wd: qmoe.qmoe(
+            x, idx, probs, wg, wu, wd, d=D, r_g=0.004, s_g=0.05, r_u=0.004, r_h=2.0, r_d=0.02,
+        ),
+        spec((rows, D), jnp.int8), spec((rows, k), jnp.int32), spec((rows, e), jnp.float32),
+        spec((e, D, f), jnp.int8), spec((e, D, f), jnp.int8), spec((e, f // 2, D), jnp.uint8),
+    )
+    calls = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and calls[0].lstrip().startswith("%qmoe"), calls
 
 
 @pytest.mark.parametrize("lut_dtype", [jnp.int8, jnp.uint8])
