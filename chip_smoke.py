@@ -46,6 +46,7 @@ from repro.serving.token_path import (  # noqa: E402
     CompiledTokenAdapter,
     CompiledTokenPath,
     TokenPathConfig,
+    causal_mask,
     decode_jax,
     prefill_jax,
 )
@@ -109,7 +110,7 @@ class MirrorCheckedAdapter(CompiledTokenAdapter):
 
     def prefill(self, padded, plen, max_len):
         row, cache = super().prefill(padded, plen, max_len)
-        mask = self._causal_mask(1, padded.shape[1])
+        mask = causal_mask(1, padded.shape[1])
         ref_logits, ref_kv = prefill_jax(self.cfg, self.tp.params, padded, mask)
         what = f"prefill {self.prefills} ({plen} tokens)"
         self._require_kv(what, cache, ref_kv)

@@ -79,6 +79,7 @@ __all__ = [
     "decode_jax",
     "CompiledTokenPath",
     "CompiledTokenAdapter",
+    "causal_mask",
 ]
 
 
@@ -726,6 +727,8 @@ class CompiledTokenPath:
         self._routing_steps = 0
         # jitted one-dispatch decode steps, keyed by exact (N, S) cell
         self._step_fns: Dict[Tuple[int, int], object] = {}
+        # jitted one-dispatch prefill steps, keyed by prompt bucket S
+        self._prefill_fns: Dict[int, object] = {}
 
     # -- direct run API -------------------------------------------------------
     def prefill(self, tokens: np.ndarray, mask: np.ndarray, positions: Optional[np.ndarray] = None):
@@ -870,6 +873,63 @@ class CompiledTokenPath:
             self.flush_routing()
         return logits, nxt
 
+    def prefill_step(self, tokens, plen: int):
+        """One prompt's prefill, with nothing brought to the host but the
+        logits row the next token is chosen from.
+
+        ``tokens`` is the prompt padded to its bucket, ``(1, S)``, and
+        ``plen`` its true length.  :meth:`prefill` fetches every output: the
+        ``(1, S, V)`` f32 logits and each layer's K/V rows.  Here one jitted
+        function builds the causal mask (and a rotary block's positions
+        ``0..S-1``) on the device, runs the plan, and takes the logits row
+        at ``plen - 1`` with a dynamic index: ``plen`` is traced, so there is
+        one program per bucket, not one per prompt length.  The plan comes
+        from the shared PlanCache on every call, so cell accounting is that
+        of :meth:`prefill`.  Returns (logits row ``(V,)`` ndarray, {state-input
+        name: ``(1, S, D)`` int8 device array}); a sparse-expert block's
+        routing comes to the host as well and is counted as by
+        :meth:`prefill`.  ``tokenpath.prefill.fetched_bytes`` counts the
+        bytes brought to the host.  Falls back to :meth:`prefill` where ``S``
+        is not a bucket of the plan."""
+        tokens = np.asarray(tokens, np.int32)
+        s = tokens.shape[1]
+        if tokens.shape[0] != 1 or not 1 <= plen <= s:
+            raise ValueError(f"prefill_step takes one prompt (1, S) with 1 <= plen <= S, got "
+                             f"{tokens.shape} and plen {plen}")
+        cm = self.prefill_cm
+        fetched = default_registry().counter("tokenpath.prefill.fetched_bytes")
+        if cm.bucket_for("S", s) != s:
+            logits, rows = self.prefill(tokens, causal_mask(1, s))
+            routing = self.last_routing.nbytes if self._experts_prefill else 0
+            fetched.inc(logits.nbytes + sum(v.nbytes for v in rows.values()) + routing)
+            return logits[0, plen - 1], rows
+        plan, _ = cm.specialized({"N": 1, "S": s})  # per-call cell accounting
+        entry = self._prefill_fns.get(s)
+        if entry is None:
+            logits_name, kv, experts = self._logits_prefill, self._prefill_kv, self._experts_prefill
+            rotary = "positions" in cm.input_names
+
+            def step(params, toks, plen):
+                feeds = {"tokens": toks, "mask": jnp.tril(jnp.ones((s, s), jnp.float32))[None]}
+                if rotary:
+                    feeds["positions"] = jnp.arange(s, dtype=jnp.int32)[None]
+                outs = plan.execute(feeds, params)
+                row = jax.lax.dynamic_index_in_dim(outs[logits_name][0], plen - 1, keepdims=False)
+                routing = jnp.stack([outs[name] for name in experts]) if experts else None
+                return row, {inp: outs[name] for inp, name in kv.items()}, routing
+
+            entry = self._prefill_fns[s] = (jax.jit(step), plan.params())
+        fn, params = entry
+        with _trace.span("tokenpath.prefill.dispatch"):
+            row, rows, routing = fn(params, jnp.asarray(tokens), np.int32(plen))
+        with _trace.span("tokenpath.prefill.fetch"):
+            row, routing = jax.device_get((row, routing))
+        fetched.inc(row.nbytes + (0 if routing is None else routing.nbytes))
+        if routing is not None:
+            self.last_routing = routing
+            self._count_routing(routing, decode=False)
+        return row, rows
+
     def init_cache(self, n: int, s: int) -> Dict[str, np.ndarray]:
         D = self.cfg.d_model
         return {spec.input: np.zeros((n, s, D), np.int8) for spec in self.state_specs}
@@ -898,6 +958,12 @@ def _routing_counts(routing: jax.Array, n_experts: int) -> jax.Array:
 def _count_host_trip() -> None:
     """One whole KV cache moved between host and device by the token path."""
     default_registry().counter("tokenpath.cache.host_trips").inc()
+
+
+def causal_mask(n: int, s: int) -> np.ndarray:
+    """The prefill graph's causal ``mask`` ``(n, s, s)`` f32: 1 where a
+    position may attend (keys at or before it), 0 elsewhere."""
+    return np.broadcast_to(np.tril(np.ones((s, s), np.float32)), (n, s, s)).copy()
 
 
 def _write_rows(cache, rows, slot):
@@ -933,18 +999,11 @@ class CompiledTokenAdapter:
         self.max_len = max_len
         return jax.device_put(self.tp.init_cache(slots, max_len))
 
-    @staticmethod
-    def _causal_mask(n: int, s: int) -> np.ndarray:
-        return np.broadcast_to(
-            np.tril(np.ones((s, s), np.float32)), (n, s, s)
-        ).copy()
-
     def prefill(self, padded: np.ndarray, plen: int, max_len: int):
-        bucket = padded.shape[1]
-        with _trace.span("tokenpath.prefill.mask"):
-            mask = self._causal_mask(1, bucket)
-        logits, cache = self.tp.prefill(padded, mask, np.arange(bucket)[None])
-        return logits[0, plen - 1], cache
+        """The prompt's logits row at ``plen - 1`` on the host and its K/V
+        rows on the device, for :meth:`scatter`
+        (:meth:`CompiledTokenPath.prefill_step`)."""
+        return self.tp.prefill_step(padded, plen)
 
     def scatter(self, cache, slot: int, pcache):
         """Write one prompt's K/V rows ``(1, bucket, D)`` into ``slot`` of the

@@ -7,7 +7,9 @@ shared expert of 96, 2 layers, seeded random weights.
   (``repro.serving.moe_reference``) and to the jnp mirrors, bit for bit;
 - the grouped ``qmoe`` kernel against its oracle where experts get no rows,
   where every row goes to one expert, and with odd rows per expert;
-- the fused plan step against the unfused semantic region.
+- the fused plan step against the unfused semantic region;
+- the adapter's device prefill against the host prefill: the same logits
+  row, K/V rows, routing and routing counts.
 """
 import jax
 import jax.numpy as jnp
@@ -19,10 +21,13 @@ from repro.core.runtime import ReferenceRuntime
 from repro.kernels import pack
 from repro.kernels import qmoe as qmoe_kernel
 from repro.kernels import ref as kref
+from repro.obs.metrics import default_registry
 from repro.serving import moe_reference
 from repro.serving.token_path import (
+    CompiledTokenAdapter,
     CompiledTokenPath,
     TokenPathConfig,
+    causal_mask,
     decode_jax,
     make_token_params,
     prefill_jax,
@@ -44,10 +49,6 @@ def tp(request, params):
     return CompiledTokenPath(CFG, params, backend=request.param, s_granularity=16)
 
 
-def _causal(n, s):
-    return np.broadcast_to(np.tril(np.ones((s, s), np.float32)), (n, s, s)).copy()
-
-
 def test_every_layer_fuses_its_experts(tp):
     for cm in (tp.prefill_cm, tp.decode_cm):
         assert cm.stats["fused_qmoe"] == CFG.n_layers
@@ -66,8 +67,8 @@ def test_prefill_then_decode_equals_the_reference_and_the_mirrors(tp, params):
     plen, steps, cache_len = 9, 5, 16
     prompt = rng.integers(1, CFG.vocab, (1, plen)).astype(np.int32)
     pos = np.arange(plen)[None].astype(np.int32)
-    logits, rows = tp.prefill(prompt, _causal(1, plen), pos)
-    want, _ = prefill_jax(CFG, params, prompt, _causal(1, plen), positions=pos)
+    logits, rows = tp.prefill(prompt, causal_mask(1, plen), pos)
+    want, _ = prefill_jax(CFG, params, prompt, causal_mask(1, plen), positions=pos)
     np.testing.assert_array_equal(logits, np.asarray(want))
     cache = {k: np.zeros((1, cache_len, CFG.d_model), np.int8) for k in rows}
     for k in rows:
@@ -90,6 +91,40 @@ def test_prefill_then_decode_equals_the_reference_and_the_mirrors(tp, params):
         tok = int(out[0].argmax())
     ref = np.asarray(moe_reference.forward(CFG, params, np.array(served, np.int32)))
     np.testing.assert_array_equal(np.concatenate(rows_out), ref)
+
+
+ROUTING = ("tokenpath.moe.rows", "tokenpath.moe.experts_hit", "tokenpath.moe.layer_calls")
+
+
+@pytest.mark.parametrize("bucket,plen", [(16, 1), (16, 15), (16, 16), (32, 1), (32, 31), (32, 32)])
+def test_the_device_prefill_equals_the_host_prefill(tp, bucket, plen):
+    """``CompiledTokenAdapter.prefill`` (one jitted step: the logits row at
+    ``plen - 1`` and the routing to the host, the K/V rows left on the
+    device) against ``CompiledTokenPath.prefill``, bit for bit; the routing
+    is counted the same, and only the row and the routing are fetched."""
+    reg = default_registry()
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :plen] = np.random.default_rng(40 + plen).integers(1, CFG.vocab, plen)
+
+    def counted(fn):
+        before = [reg.counter(n).value for n in ROUTING + ("tokenpath.prefill.fetched_bytes",)]
+        out = fn()
+        after = [reg.counter(n).value for n in ROUTING + ("tokenpath.prefill.fetched_bytes",)]
+        return out, [b - a for a, b in zip(before, after)]
+
+    (logits, cache), host_counts = counted(
+        lambda: tp.prefill(padded, causal_mask(1, bucket), np.arange(bucket)[None]))
+    host_routing = tp.last_routing
+    (row, rows), counts = counted(lambda: CompiledTokenAdapter(tp).prefill(padded, plen, 64))
+    np.testing.assert_array_equal(row, logits[0, plen - 1])
+    for name, got in rows.items():
+        assert isinstance(got, jax.Array)
+        np.testing.assert_array_equal(np.asarray(got), cache[name])
+    assert isinstance(tp.last_routing, np.ndarray)
+    assert tp.last_routing.shape == (CFG.n_layers, 1, bucket, CFG.top_k)
+    np.testing.assert_array_equal(tp.last_routing, host_routing)
+    assert counts[:3] == host_counts[:3]
+    assert counts[3] == CFG.vocab * 4 + tp.last_routing.nbytes
 
 
 def _expert_weights(e=8, d=64, f=96, seed=0):
@@ -166,7 +201,7 @@ def test_the_fused_step_equals_the_unfused_region(params):
     n, s = 2, 12
     feeds = {
         "tokens": rng.integers(1, CFG.vocab, (n, s)).astype(np.int32),
-        "mask": _causal(n, s),
+        "mask": causal_mask(n, s),
         "positions": np.broadcast_to(np.arange(s), (n, s)).astype(np.int32).copy(),
     }
     fused = tp.prefill_cm.run(feeds)

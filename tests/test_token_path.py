@@ -37,6 +37,7 @@ from repro.serving.token_path import (
     CompiledTokenPath,
     TokenPathConfig,
     build_decode_model,
+    causal_mask,
     decode_jax,
     make_token_params,
     prefill_jax,
@@ -44,10 +45,6 @@ from repro.serving.token_path import (
 
 CFG = TokenPathConfig()  # defaults: mixed w4 (qkv, down) / w8 (o, up)
 PARAMS = make_token_params(CFG, seed=3)
-
-
-def _causal(n, s):
-    return np.broadcast_to(np.tril(np.ones((s, s), np.float32)), (n, s, s)).copy()
 
 
 def _tokens(rng, n, s):
@@ -75,7 +72,7 @@ class TestDifferentialSweep:
         tp = _tp(backend)
         rng = np.random.default_rng(100 * n + plen)
         toks = _tokens(rng, n, plen)
-        mask = _causal(n, plen)
+        mask = causal_mask(n, plen)
         logits, cache = tp.prefill(toks, mask)
         jl, jcaches = prefill_jax(CFG, PARAMS, toks, mask)
         np.testing.assert_array_equal(logits, np.asarray(jl))
@@ -90,7 +87,7 @@ class TestDifferentialSweep:
         rng = np.random.default_rng(7 * n + plen)
         s_max = 16
         toks = _tokens(rng, n, plen)
-        _, pcache = tp.prefill(toks, _causal(n, plen))
+        _, pcache = tp.prefill(toks, causal_mask(n, plen))
         cache = tp.init_cache(n, s_max)
         for k in cache:
             cache[k][:, :plen] = pcache[k][:, :plen]
@@ -114,7 +111,7 @@ class TestDifferentialSweep:
         tp = _tp("ref")
         rng = np.random.default_rng(0)
         toks = _tokens(rng, 2, 6)
-        mask = _causal(2, 6)
+        mask = causal_mask(2, 6)
         logits, _ = tp.prefill(toks, mask)
         want = ReferenceRuntime(tp.prefill_model).run({"tokens": toks, "mask": mask})
         np.testing.assert_array_equal(
@@ -300,7 +297,7 @@ class TestSharedCacheServing:
         bucket, s_max, plen = 8, 16, len(prompt)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :plen] = prompt
-        jl, jcaches = prefill_jax(CFG, PARAMS, padded, _causal(1, bucket))
+        jl, jcaches = prefill_jax(CFG, PARAMS, padded, causal_mask(1, bucket))
         states = []
         for k, v in jcaches:
             ks = np.zeros((1, s_max, CFG.d_model), np.int8)
@@ -327,10 +324,8 @@ SPAN_PARENTS = {
     "engine.step": None,
     "engine.admit": "engine.step",
     "engine.prefill": "engine.admit",
-    "tokenpath.prefill.mask": "engine.prefill",
-    "run.pad": "engine.prefill",
-    "run.execute": "engine.prefill",
-    "run.slice": "engine.prefill",
+    "tokenpath.prefill.dispatch": "engine.prefill",
+    "tokenpath.prefill.fetch": "engine.prefill",
     "engine.scatter": "engine.admit",
     "tokenpath.scatter.put": "engine.scatter",
     "tokenpath.scatter.dispatch": "engine.scatter",
@@ -386,6 +381,9 @@ class TestTokenPathSpans:
         spans = [r for r in by_sid.values() if r.name in SPAN_PARENTS]
         # the cache stays on the device, so the decode never puts it there
         assert {r.name for r in spans} == set(SPAN_PARENTS) - {"tokenpath.decode.put"}
+        # the prefill runs as one jitted step: no host mask, no CompiledModel.run
+        for name in ("tokenpath.prefill.mask", "run.pad", "run.execute", "run.slice"):
+            assert not tracer.spans(name), name
         for r in spans:
             parent = by_sid[r.parent].name if r.parent is not None else None
             assert parent == SPAN_PARENTS[r.name], r
@@ -394,7 +392,9 @@ class TestTokenPathSpans:
         # request spans carry their uid
         assert sorted(s.attrs["uid"] for s in tracer.spans("engine.prefill")) == [r.uid for r in reqs]
         assert {s.attrs["uid"] for s in tracer.spans("engine.scatter")} == {r.uid for r in reqs}
-        # one put and one dispatch per admission
+        # one put and one dispatch per admission, one dispatch and fetch per prefill
+        assert len(tracer.spans("tokenpath.prefill.dispatch")) == len(reqs)
+        assert len(tracer.spans("tokenpath.prefill.fetch")) == len(reqs)
         assert len(tracer.spans("tokenpath.scatter.put")) == len(reqs)
         assert len(tracer.spans("tokenpath.scatter.dispatch")) == len(reqs)
 
@@ -606,6 +606,99 @@ class TestDeviceScatter:
             ad.scatter(cache, 3, rows)
 
 
+class TestDevicePrefill:
+    """``CompiledTokenAdapter.prefill`` through ``CompiledTokenPath.prefill_step``:
+    only the logits row at ``plen - 1`` comes to the host, and the K/V rows
+    stay on the device for ``scatter``."""
+
+    S = 16
+    FETCHED = "tokenpath.prefill.fetched_bytes"
+
+    @staticmethod
+    def _padded(plen, bucket, seed=31):
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, :plen] = _tokens(np.random.default_rng(seed + plen), 1, plen)[0]
+        return padded
+
+    @pytest.mark.parametrize("backend", ["ref", "interpret"])
+    @pytest.mark.parametrize("bucket,plen", [(8, 1), (8, 7), (8, 8), (16, 1), (16, 15), (16, 16)])
+    def test_equals_the_host_prefill_bit_for_bit(self, backend, bucket, plen):
+        tp = _tp(backend)
+        padded = self._padded(plen, bucket)
+        row, rows = CompiledTokenAdapter(tp).prefill(padded, plen, self.S)
+        logits, cache = tp.prefill(padded, causal_mask(1, bucket))
+        assert isinstance(row, np.ndarray) and row.shape == (CFG.vocab,)
+        np.testing.assert_array_equal(row, logits[0, plen - 1])
+        assert set(rows) == set(cache)
+        for name, got in rows.items():
+            assert isinstance(got, jax.Array) and got.shape == (1, bucket, CFG.d_model)
+            np.testing.assert_array_equal(np.asarray(got), cache[name])
+
+    def test_one_program_per_bucket_whatever_plen(self):
+        tp = _tp("ref")
+        ad = CompiledTokenAdapter(tp)
+        served = [(8, p) for p in (8, 1, 3, 5, 7)] + [(16, p) for p in (16, 9, 2)]
+        for bucket, plen in served:
+            ad.prefill(self._padded(plen, bucket), plen, self.S)
+        assert sorted(tp._prefill_fns) == [8, 16]
+        assert [tp._prefill_fns[b][0]._cache_size() for b in (8, 16)] == [1, 1]
+        # the plan cache counts the prefill cells as prefill() does: one miss each
+        stats = tp.cache_stats()
+        assert stats["misses"] == 2 and stats["hits"] == len(served) - 2
+
+    def test_scatter_takes_the_device_rows_as_they_are(self):
+        tp = _tp("ref")
+        ad = CompiledTokenAdapter(tp)
+        plen, bucket, slot = 5, 8, 1
+        _, rows = ad.prefill(self._padded(plen, bucket), plen, self.S)
+        cache = ad.init_cache(2, self.S)
+        handed = []
+        write = ad._write_rows
+        ad._write_rows = lambda c, r, sl: (handed.append(r), write(c, r, sl))[1]
+        trips = default_registry().counter("tokenpath.cache.host_trips")
+        before = trips.value
+        got = ad.scatter(cache, slot, rows)
+        assert trips.value == before
+        # the very arrays prefill returned: no copy to the host and back
+        assert all(handed[0][name] is rows[name] for name in rows)
+        for name, buf in got.items():
+            want = np.zeros((2, self.S, CFG.d_model), np.int8)
+            want[slot, :bucket] = np.asarray(rows[name])[0]
+            np.testing.assert_array_equal(np.asarray(buf), want)
+
+    def test_fetched_bytes_are_one_logits_row_per_prefill(self):
+        tp = _tp("ref")
+        fetched = default_registry().counter(self.FETCHED)
+        before = fetched.value
+        eng = ServeEngine(ecfg=EngineConfig(slots=2, max_len=self.S, prefill_bucket=8),
+                          adapter=CompiledTokenAdapter(tp))
+        rng = np.random.default_rng(32)
+        for i in range(4):
+            eng.submit(Request(uid=i, prompt=_tokens(rng, 1, int(rng.integers(1, 16)))[0], max_new_tokens=3))
+        eng.run_until_drained()
+        assert eng.metrics["prefills"] == 4
+        assert fetched.value - before == eng.metrics["prefills"] * CFG.vocab * 4
+
+    def test_an_off_bucket_prompt_falls_back_to_the_host_prefill(self):
+        tp = _tp("ref")  # S buckets of 8: a prompt padded to 4 is off bucket
+        fetched = default_registry().counter(self.FETCHED)
+        before = fetched.value
+        padded = self._padded(3, 4)
+        row, rows = CompiledTokenAdapter(tp).prefill(padded, 3, self.S)
+        logits, cache = tp.prefill(padded, causal_mask(1, 4))
+        np.testing.assert_array_equal(row, logits[0, 2])
+        for name in cache:
+            np.testing.assert_array_equal(np.asarray(rows[name]), cache[name])
+        assert not tp._prefill_fns
+        # the whole logits and the K/V rows came to the host
+        assert fetched.value - before == logits.nbytes + sum(v.nbytes for v in cache.values())
+
+    @pytest.mark.parametrize("plen", [0, 9])
+    def test_plen_outside_the_bucket_is_refused(self, plen):
+        with pytest.raises(ValueError, match="plen"):
+            _tp("ref").prefill_step(self._padded(1, 8), plen)
+
+
 class TestAttentionLane:
     def test_matcher_counts_regions(self):
         tp = _tp("ref")
@@ -625,7 +718,7 @@ class TestAttentionLane:
             "q": rng.integers(-128, 128, (2, 7, 32)).astype(np.int8),
             "k": rng.integers(-128, 128, (2, 7, 32)).astype(np.int8),
             "v": rng.integers(-128, 128, (2, 7, 32)).astype(np.int8),
-            "mask": _causal(2, 7),
+            "mask": causal_mask(2, 7),
         }
         dyn = {"N": None, "S": None}
         ref = compile_model(m, backend="ref", batch="dynamic", dynamic_axes=dyn)
