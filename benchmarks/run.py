@@ -46,9 +46,8 @@ Prints ``name,us_per_call,derived`` CSV rows:
   sys_attn_decode        — the compiled token path (docs/token_path.md):
                            transformer decode through the specialized
                            ("N",1,…) ExecutionPlan with int8 KV state slots
-                           and fused attention, vs the opaque-JAX engine at
-                           the same geometry, decode cells M ∈ {1,8}
-                           (derived: tokens/s both ways per cell; asserts
+                           and fused attention, decode cells M ∈ {1,8}
+                           (derived: tokens/s per cell; asserts
                            compiled decode bit-exact vs the jnp mirror and
                            exactly one specialization per visited cell)
   sys_w8a8_decode        — reduced-arch decode step: bf16 vs W8A8+int8-KV
@@ -671,17 +670,11 @@ def bench_attn_decode():
     """The compiled token path on the decode hot loop: the transformer block
     (QKV/O + int8-KV update + fused attention + MLP) executing through the
     specialized ``("N",1,…)`` ExecutionPlan — int8 KV cache in state slots,
-    mixed w4/w8 projections — vs the opaque jitted-JAX engine path at the
-    same geometry, at decode cells M ∈ {1, 8}.  Before timing: the compiled
-    step must be bit-exact against the jnp mirror (``decode_jax``) at every
-    cell, and the shared PlanCache must hold exactly one specialization per
-    visited cell (zero per-step re-lowering).  See docs/token_path.md."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs.base import ModelConfig
-    from repro.models import model as M
-    from repro.serving.engine import OpaqueModelAdapter
+    mixed w4/w8 projections — at decode cells M ∈ {1, 8}.  Before timing:
+    the compiled step must be bit-exact against the jnp mirror
+    (``decode_jax``) at every cell, and the shared PlanCache must hold
+    exactly one specialization per visited cell (zero per-step
+    re-lowering).  See docs/token_path.md."""
     from repro.serving.token_path import (
         CompiledTokenAdapter,
         CompiledTokenPath,
@@ -694,15 +687,6 @@ def bench_attn_decode():
     params = make_token_params(cfg, seed=3)
     tp = CompiledTokenPath(cfg, params, backend="ref", s_granularity=8)
     ad = CompiledTokenAdapter(tp)
-
-    # opaque baseline: a decoder of the same geometry on the bf16 JAX path
-    ocfg = ModelConfig(
-        name="tiny-opaque", family="decoder", n_layers=cfg.n_layers,
-        d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv_heads=cfg.n_heads,
-        d_ff=cfg.d_ff, vocab_size=cfg.vocab, mlp_type="gelu",
-    )
-    oparams = M.init_params(jax.random.PRNGKey(0), ocfg)
-    oad = OpaqueModelAdapter(oparams, ocfg, compute_dtype=jnp.bfloat16)
 
     rng = np.random.default_rng(11)
     s_max, pos0 = 32, 10
@@ -735,13 +719,7 @@ def bench_attn_decode():
             assert np.array_equal(nxt[tp.state_specs[2 * l + 1].input], np.asarray(vj))
 
         us_c = _timeit(lambda: ad.decode(toks, pos, cache))
-        ocache = oad.init_cache(m, s_max)
-        us_o = _timeit(lambda: jax.block_until_ready(oad.decode(toks, pos, ocache)[0]))
-        parts.append(
-            f"tok_s_b{m}_compiled={m / (us_c * 1e-6):.0f};"
-            f"tok_s_b{m}_opaque={m / (us_o * 1e-6):.0f};"
-            f"speedup_b{m}={us_o / us_c:.2f}x"
-        )
+        parts.append(f"tok_s_b{m}_compiled={m / (us_c * 1e-6):.0f}")
 
     # exactly one specialization per visited decode cell, all hits after
     stats = tp.cache_stats()

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, for the program's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``repro.launch.serve``)
+Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``bench/harness.py``)
 call :func:`enable_compile_cache` once at start-up; importing the library
 never does.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps
 its cache there and nothing is set in code.  Otherwise the cache goes to a

@@ -5,25 +5,19 @@
   admission is a host-side decision — the decode step never re-compiles).
 * Prefill runs per admitted request (right-padded to a bucket length to bound
   recompiles), then its KV cache is scattered into the slot's region.
-* ``kv_cache_dtype="int8"`` serves with the paper's symmetric int8 cache.
 
 The engine core (queue, slot bookkeeping, sampling, metrics) is model-
-agnostic: all model execution goes through a *token-path adapter* with four
-methods — ``init_cache`` / ``prefill`` / ``decode`` / ``scatter``.  Two
-adapters exist:
-
-* :class:`OpaqueModelAdapter` (default) — the original jitted-JAX seam:
-  ``repro.models.model`` prefill/decode with one jitted prefill per prompt
-  bucket and a single jitted decode step.
-* :class:`repro.serving.token_path.CompiledTokenAdapter` — the PQ-IR lane:
-  prefill and decode are :class:`~repro.core.compile.CompiledModel` plans
-  sharing one :class:`~repro.backend.plan.PlanCache`, the KV cache is the
-  plan's persistent int8 state slots, and every decode step executes a
-  pre-specialized ExecutionPlan (zero per-step re-lowering).
+agnostic: all model execution goes through one *token-path adapter* seam of
+four methods — ``init_cache(slots, max_len)``, ``prefill(padded, plen,
+max_len)``, ``scatter(cache, slot, pcache)`` and ``decode(toks, pos, cache)``.
+Behind it sits :class:`repro.serving.token_path.CompiledTokenAdapter`, the
+compiled token path: prefill and decode are jitted steps over pre-specialized
+ExecutionPlans sharing one :class:`~repro.backend.plan.PlanCache`, the int8
+KV cache is the plans' persistent state slots and stays on the device, and
+every call runs at the plans' bucket extents (zero per-step re-lowering).
 
 At fleet scale the same structure runs per model replica with the scheduler
-sharded by a front-end router; the engine here is single-replica but the
-step functions are the pjit-able ones from repro.launch.steps.
+sharded by a front-end router; the engine here is single-replica.
 """
 from __future__ import annotations
 
@@ -32,13 +26,10 @@ import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..backend.plan import PlanCache, bucket_multiple
-from ..configs.base import ModelConfig
-from ..models import model as M
+from ..backend.plan import bucket_multiple
 from ..obs import trace as _trace
 from ..obs.metrics import MetricsRegistry
 
@@ -66,20 +57,6 @@ class EngineConfig:
     temperature: float = 1.0  # sampling path only (greedy=False)
     top_k: int = 0  # 0 ⇒ sample the full vocab
     seed: int = 0  # host-side sampling rng seed
-    # resident jitted prefill fns (LRU beyond); None = one per possible
-    # prompt bucket (max_len // prefill_bucket) so steady traffic over the
-    # full bucket range never thrashes — the bound exists for configs where
-    # that product is large, not to cause recompiles in the common case
-    prefill_cache_size: Optional[int] = None
-
-
-def _prefill_capacity(ecfg: "EngineConfig") -> int:
-    """Resolve the prefill-cache bound: explicit config wins, else one slot
-    per reachable prompt bucket (prompts are padded to multiples of
-    ``prefill_bucket`` and capped by ``max_len``)."""
-    if ecfg.prefill_cache_size is not None:
-        return ecfg.prefill_cache_size
-    return max(1, ecfg.max_len // ecfg.prefill_bucket)
 
 
 #: Module-level fallback sampler state: callers that don't thread an rng
@@ -124,117 +101,21 @@ def sample_token(
     return int(rng.choice(z.size, p=p))
 
 
-class OpaqueModelAdapter:
-    """The engine's original jitted-JAX token path, behind the adapter seam.
-
-    One jitted prefill per prompt bucket (bounded LRU — adversarial
-    prompt-length traffic would otherwise pin one jitted fn per bucket
-    forever; sizes surface in the engine metrics), one jitted decode step.
-    The prefill cache is the same :class:`PlanCache` (LRU + uniform
-    hit/miss/hit_rate accounting) the compiled-model path uses for its
-    per-bucket plan specializations — the prefill path is the token engine's
-    instance of exactly that per-shape discipline.
-    """
-
-    def __init__(
-        self,
-        params,
-        cfg: ModelConfig,
-        *,
-        compute_dtype=jnp.float32,
-        prefill_cache_capacity: int = 8,
-    ) -> None:
-        self.params = params
-        self.cfg = cfg
-        self.compute_dtype = compute_dtype
-        self._decode = jax.jit(
-            lambda p, t, pos, c: M.decode_step(p, t, pos, c, cfg, compute_dtype=compute_dtype)
-        )
-        self.prefill_cache: PlanCache = PlanCache(prefill_cache_capacity, scope="prefill")
-
-    def init_cache(self, slots: int, max_len: int):
-        return M.init_cache(self.cfg, slots, max_len)
-
-    def _prefill_fn(self, plen: int):
-        jitted = self.prefill_cache.get(plen)
-        if jitted is None:
-            cfg, dt = self.cfg, self.compute_dtype
-
-            def fn(params, tokens, cache):
-                return M.prefill(params, {"tokens": tokens}, cfg, cache, compute_dtype=dt, q_chunk=min(plen, 512), kv_chunk=min(plen, 512))
-
-            jitted = jax.jit(fn)
-            self.prefill_cache.put(plen, jitted)
-        return jitted
-
-    def prefill(self, padded: np.ndarray, plen: int, max_len: int):
-        """Run one right-padded prompt ``(1, bucket)``; returns the logits row
-        for the true last prompt token and the single-request KV cache."""
-        bucket = padded.shape[1]
-        pcache = M.init_cache(self.cfg, 1, max_len)
-        logits, pcache = self._prefill_fn(bucket)(self.params, jnp.asarray(padded), pcache)
-        return self._logits_at(padded, plen, logits, pcache)
-
-    def _logits_at(self, padded, plen, last_logits, pcache):
-        """Logits for the true last prompt token (bucket may extend past it)."""
-        if plen == padded.shape[1]:
-            return last_logits[0], pcache
-        # re-run a single decode on position plen-1's token? simpler: prefill
-        # returns last-position logits; for bucketed prompts recompute from the
-        # cached hidden is avoided by decoding token plen-1 explicitly.
-        tok = jnp.asarray(padded[:, plen - 1 : plen])
-        pos = jnp.full((1,), plen - 1, jnp.int32)
-        logits, _ = self._decode(self.params, tok, pos, pcache)
-        return logits[0], pcache
-
-    def decode(self, toks: np.ndarray, pos: np.ndarray, cache):
-        """One batched decode step over all slots; positions are per-slot."""
-        return self._decode(self.params, jnp.asarray(toks), jnp.asarray(pos), cache)
-
-    def scatter(self, cache, slot: int, pcache):
-        """Write a prefilled single-request cache into one slot's region."""
-        def scat(dst, src):
-            if dst.ndim == src.ndim and dst.shape[1:] == src.shape[1:] and src.shape[0] == 1:
-                return dst.at[slot : slot + 1].set(src)
-            # stacked layer dim first: (L, B, ...) — batch is axis 1
-            return dst.at[:, slot : slot + 1].set(src)
-
-        return jax.tree.map(scat, cache, pcache)
-
-
 class ServeEngine:
     def __init__(
         self,
-        params=None,
-        cfg: Optional[ModelConfig] = None,
-        ecfg: EngineConfig = None,
+        ecfg: EngineConfig,
+        adapter,
         *,
-        compute_dtype=jnp.float32,
         registry: Optional[MetricsRegistry] = None,
-        adapter=None,
     ) -> None:
-        if ecfg is None:
-            raise ValueError("ServeEngine requires an EngineConfig")
         # cache length must cover the largest prefill bucket (same round-up-
         # to-multiple policy the compiled-model grid uses for sequence axes)
         ecfg = dataclasses.replace(
             ecfg, max_len=bucket_multiple(ecfg.max_len, ecfg.prefill_bucket)
         )
         self.ecfg = ecfg
-        if adapter is None:
-            if params is None or cfg is None:
-                raise ValueError(
-                    "ServeEngine needs either (params, cfg) for the default "
-                    "OpaqueModelAdapter or an explicit adapter="
-                )
-            adapter = OpaqueModelAdapter(
-                params, cfg, compute_dtype=compute_dtype,
-                prefill_cache_capacity=_prefill_capacity(ecfg),
-            )
         self.adapter = adapter
-        self.params = getattr(adapter, "params", params)
-        self.cfg = getattr(adapter, "cfg", cfg)
-        self.compute_dtype = compute_dtype
         self.queue: Deque[Request] = deque()
         self.active: Dict[int, Request] = {}  # slot -> request
         self.slot_pos = np.zeros((ecfg.slots,), np.int32)
@@ -242,24 +123,10 @@ class ServeEngine:
         self.slot_budget = np.zeros((ecfg.slots,), np.int32)
         self.cache = adapter.init_cache(ecfg.slots, ecfg.max_len)
         self._rng = np.random.default_rng(ecfg.seed)
-        # per-instance registry unless the caller injects a shared one; the
-        # adapter's prefill cache (when it keeps one) publishes its canonical
-        # cache.prefill.* gauges and the flat prefill_cache_* keys below stay
-        # as read-only aliases
+        # per-instance registry unless the caller injects a shared one
         self.registry = registry if registry is not None else MetricsRegistry()
-        self._prefill_cache: Optional[PlanCache] = getattr(adapter, "prefill_cache", None)
-        if self._prefill_cache is not None:
-            self._prefill_cache.attach_metrics(self.registry)
         self._queue_wait = self.registry.histogram("engine.queue_wait_ms")
-        self.metrics = {
-            "decode_steps": 0,
-            "prefills": 0,
-            "completed": 0,
-            "prefill_cache_size": 0,
-            "prefill_cache_hits": 0,
-            "prefill_cache_evictions": 0,
-            "prefill_cache_hit_rate": 0.0,
-        }
+        self.metrics = {"decode_steps": 0, "prefills": 0, "completed": 0}
 
     def _count(self, key: str, n: int = 1) -> None:
         """One accounting site: the flat alias dict and the canonical
@@ -291,7 +158,7 @@ class ServeEngine:
             raise ValueError(f"max_new_tokens must be >= 1, got {req.max_new_tokens}")
         bucket = bucket_multiple(plen, self.ecfg.prefill_bucket)
         if bucket > self.ecfg.max_len or (req.max_new_tokens > 1 and plen >= self.ecfg.max_len):
-            # the per-slot KV cache is init_cache(cfg, 1, max_len): a prefill
+            # each slot's KV region holds max_len positions: a prefill
             # bucket beyond it (or a decode position at max_len) would clip
             # the cache write silently — reject instead
             raise ValueError(
@@ -302,15 +169,6 @@ class ServeEngine:
         req.t_submit = time.monotonic()
         req.generated = []
         self.queue.append(req)
-
-    def _sync_cache_metrics(self) -> None:
-        if self._prefill_cache is None:
-            return
-        stats = self._prefill_cache.stats
-        self.metrics["prefill_cache_size"] = stats["size"]
-        self.metrics["prefill_cache_hits"] = stats["hits"]
-        self.metrics["prefill_cache_evictions"] = stats["evictions"]
-        self.metrics["prefill_cache_hit_rate"] = stats["hit_rate"]
 
     def _admit(self) -> None:
         with _trace.span("engine.admit"):
@@ -334,7 +192,6 @@ class ServeEngine:
             if _trace.enabled:
                 sp.set(uid=req.uid, plen=plen, bucket=bucket)
             first_logits, pcache = self.adapter.prefill(padded, plen, self.ecfg.max_len)
-        self._sync_cache_metrics()
         tok = self._select(first_logits)
         req.generated.append(tok)
         req.t_first = time.monotonic()
@@ -379,7 +236,9 @@ class ServeEngine:
     def _advance(self, logits) -> None:
         """Choose each live slot's next token and retire finished requests."""
         if self.ecfg.greedy:
-            # argmax on device: transfers `slots` ints, not slots×vocab floats
+            # the logits are already on the host: jnp.argmax uploads them
+            # again and brings back `slots` ints (choosing the token inside
+            # the decode step is ROADMAP 1.3)
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
             pick = lambda slot: int(nxt[slot])  # noqa: E731
         else:

@@ -30,8 +30,8 @@ bit-exact against the compiled artifacts — the differential sweep in
 ``tests/test_token_path.py`` pins all three runtimes against each other.
 
 :class:`CompiledTokenAdapter` plugs the compiled pair into
-:class:`repro.serving.engine.ServeEngine` behind the same adapter seam the
-opaque-JAX model uses.
+:class:`repro.serving.engine.ServeEngine` behind its four-method adapter
+seam.
 """
 from __future__ import annotations
 
@@ -809,25 +809,24 @@ class CompiledTokenPath:
         state dict flows back in untouched as device arrays, and only the
         logits are materialized on host.  The specialized entry is still
         fetched from the shared PlanCache on every call, so cell accounting
-        is identical to the slow path: one miss per first-visited cell,
-        hits thereafter.  Falls back to :meth:`decode` when the extents are
-        not bucket-aligned (then padding/slicing is required and the slow
-        path is the correct one).  Returns (logits (N, V) ndarray, next
-        cache of device arrays).  A cache that comes in on the host counts
-        one ``tokenpath.cache.host_trips``."""
+        is that of :meth:`decode`: one miss per first-visited cell, hits
+        thereafter.  ``(N, S)`` must be a cell of the decode plan; other
+        extents are refused with a ``ValueError`` naming the bucket
+        (:meth:`CompiledTokenAdapter.init_cache` allocates the engine's
+        cache there).  Returns (logits (N, V) ndarray, next cache of device
+        arrays).  A cache that comes in on the host counts one
+        ``tokenpath.cache.host_trips``."""
         n = int(np.shape(tokens)[0])
         s = int(np.shape(next(iter(cache.values())))[1])
         cm = self.decode_cm
+        if cm.bucket_for("N", n) != n or cm.bucket_for("S", s) != s:
+            raise ValueError(
+                f"decode_step runs at the decode plan's buckets: (N, S) = ({n}, {s}) needs "
+                f"({cm.bucket_for('N', n)}, {cm.bucket_for('S', s)})"
+            )
         on_host = any(isinstance(v, np.ndarray) for v in cache.values())
         if on_host:
             _count_host_trip()
-        if cm.bucket_for("N", n) != n or cm.bucket_for("S", s) != s:
-            pos = np.asarray(pos, np.int64)
-            onehot = np.zeros((n, s, 1), np.int8)
-            onehot[np.arange(n), np.clip(pos, 0, s - 1), 0] = 1
-            mask = (np.arange(s)[None, None, :] <= pos[:, None, None]).astype(np.float32)
-            logits, nxt = self.decode(tokens, onehot, mask, cache, positions=pos)
-            return logits[:, 0, :], nxt
         plan, _ = cm.specialized({"N": n, "S": s})  # per-step cell accounting
         entry = self._step_fns.get((n, s))
         if entry is None:
@@ -878,31 +877,29 @@ class CompiledTokenPath:
         logits row the next token is chosen from.
 
         ``tokens`` is the prompt padded to its bucket, ``(1, S)``, and
-        ``plen`` its true length.  :meth:`prefill` fetches every output: the
-        ``(1, S, V)`` f32 logits and each layer's K/V rows.  Here one jitted
-        function builds the causal mask (and a rotary block's positions
-        ``0..S-1``) on the device, runs the plan, and takes the logits row
+        ``plen`` its true length; where ``S`` is not a bucket of the plan,
+        the ids are padded further, with zeros, to ``bucket_for("S", S)``
+        (the padding :meth:`prefill` applies too).  :meth:`prefill` fetches
+        every output: the ``(1, S, V)`` f32 logits and each layer's K/V rows.
+        Here one jitted function builds the causal mask (and a rotary
+        block's positions ``0..S-1``) on the device, runs the plan at the
+        bucket, and takes the logits row
         at ``plen - 1`` with a dynamic index: ``plen`` is traced, so there is
         one program per bucket, not one per prompt length.  The plan comes
         from the shared PlanCache on every call, so cell accounting is that
         of :meth:`prefill`.  Returns (logits row ``(V,)`` ndarray, {state-input
-        name: ``(1, S, D)`` int8 device array}); a sparse-expert block's
+        name: ``(1, bucket, D)`` int8 device array}); a sparse-expert block's
         routing comes to the host as well and is counted as by
         :meth:`prefill`.  ``tokenpath.prefill.fetched_bytes`` counts the
-        bytes brought to the host.  Falls back to :meth:`prefill` where ``S``
-        is not a bucket of the plan."""
+        bytes brought to the host."""
         tokens = np.asarray(tokens, np.int32)
-        s = tokens.shape[1]
-        if tokens.shape[0] != 1 or not 1 <= plen <= s:
+        if tokens.shape[0] != 1 or not 1 <= plen <= tokens.shape[1]:
             raise ValueError(f"prefill_step takes one prompt (1, S) with 1 <= plen <= S, got "
                              f"{tokens.shape} and plen {plen}")
         cm = self.prefill_cm
+        s = cm.bucket_for("S", tokens.shape[1])
+        tokens = np.pad(tokens, ((0, 0), (0, s - tokens.shape[1])))
         fetched = default_registry().counter("tokenpath.prefill.fetched_bytes")
-        if cm.bucket_for("S", s) != s:
-            logits, rows = self.prefill(tokens, causal_mask(1, s))
-            routing = self.last_routing.nbytes if self._experts_prefill else 0
-            fetched.inc(logits.nbytes + sum(v.nbytes for v in rows.values()) + routing)
-            return logits[0, plen - 1], rows
         plan, _ = cm.specialized({"N": 1, "S": s})  # per-call cell accounting
         entry = self._prefill_fns.get(s)
         if entry is None:
@@ -978,26 +975,27 @@ def _write_rows(cache, rows, slot):
 class CompiledTokenAdapter:
     """ServeEngine adapter for the compiled token path.
 
-    ``init_cache``/``prefill``/``decode``/``scatter`` mirror
-    :class:`repro.serving.engine.OpaqueModelAdapter`'s seam, but every call
-    executes a pre-specialized ExecutionPlan out of the shared PlanCache —
-    after the first step per cell there is zero lowering work per token."""
+    ``init_cache``/``prefill``/``scatter``/``decode`` are the engine's seam.
+    Every served call runs a jitted step over a pre-specialized ExecutionPlan
+    out of the shared PlanCache, at the plans' bucket extents: the cache is
+    allocated at the decode plan's ``(N, S)`` buckets, a prompt is padded to
+    its ``S`` bucket and the decode batch to the cache's ``N`` with dead
+    rows.  After the first step per cell there is zero lowering work per
+    token."""
 
     def __init__(self, tp: CompiledTokenPath) -> None:
         self.tp = tp
         self.cfg = tp.cfg
-        self.max_len = 0
-        # no per-bucket jitted-fn cache here — plan specialization IS the
-        # per-bucket discipline, surfaced via tp.cache_stats()
-        self.prefill_cache = None
         # the slot is traced: one program per prompt bucket, not per slot;
         # the cache is donated, so the rows are written in place
         self._write_rows = jax.jit(_write_rows, donate_argnums=0)
 
     def init_cache(self, slots: int, max_len: int):
-        """The zero cache, on the device: admission and decode keep it there."""
-        self.max_len = max_len
-        return jax.device_put(self.tp.init_cache(slots, max_len))
+        """The zero cache at the decode plan's ``(N, S)`` buckets for
+        ``slots`` and ``max_len``, on the device: admission and decode keep
+        it there."""
+        cm = self.tp.decode_cm
+        return jax.device_put(self.tp.init_cache(cm.bucket_for("N", slots), cm.bucket_for("S", max_len)))
 
     def prefill(self, padded: np.ndarray, plen: int, max_len: int):
         """The prompt's logits row at ``plen - 1`` on the host and its K/V
@@ -1024,4 +1022,13 @@ class CompiledTokenAdapter:
             return self._write_rows(cache, rows, np.int32(slot))
 
     def decode(self, toks: np.ndarray, pos: np.ndarray, cache):
-        return self.tp.decode_step(toks, pos, cache)
+        """One decode step over the engine's ``slots`` rows: ``toks``/``pos``
+        are padded with dead rows (token 0 at position 0) up to the cache's
+        ``N``, and the first ``slots`` rows of logits come back
+        (:meth:`CompiledTokenPath.decode_step`)."""
+        n, dead = len(toks), np.shape(next(iter(cache.values())))[0] - len(toks)
+        if dead:
+            toks = np.pad(np.asarray(toks), ((0, dead), (0, 0)))
+            pos = np.pad(np.asarray(pos), (0, dead))
+        logits, nxt = self.tp.decode_step(toks, pos, cache)
+        return logits[:n], nxt

@@ -13,6 +13,26 @@ import numpy as np
 import pytest
 
 from repro.serving.engine import EngineConfig, Request, ServeEngine
+from repro.serving.token_path import CompiledTokenAdapter, CompiledTokenPath, TokenPathConfig
+
+
+def _serve(*, requests, prompt_len, new_tokens, slots, seed=0):
+    """``requests`` random prompts of ``prompt_len`` tokens, ``new_tokens``
+    each, served to the end through the compiled token path (``ref``
+    backend); returns the requests and the drained engine."""
+    tp = CompiledTokenPath(TokenPathConfig(), backend="ref", seed=seed, s_granularity=8)
+    ecfg = EngineConfig(slots=slots, max_len=prompt_len + new_tokens + 8, seed=seed)
+    eng = ServeEngine(ecfg=ecfg, adapter=CompiledTokenAdapter(tp))
+    rng = np.random.default_rng(seed)
+    reqs = [
+        Request(uid=i, prompt=rng.integers(1, tp.cfg.vocab, (prompt_len,)).astype(np.int32),
+                max_new_tokens=new_tokens)
+        for i in range(requests)
+    ]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_until_drained()
+    return reqs, eng
 
 
 def _engine_shell(**cfg_kwargs):
@@ -70,11 +90,7 @@ class TestGenerationBudget:
         """The off-by-one regression: the prefill token counts against the
         budget, so len(generated) == max_new_tokens exactly — including the
         budget-1 case, which must complete at admit without a decode."""
-        from repro.launch.serve import serve_demo
-
-        reqs, eng = serve_demo(
-            "qwen3_1_7b", requests=5, prompt_len=12, new_tokens=budget, slots=2
-        )
+        reqs, eng = _serve(requests=5, prompt_len=12, new_tokens=budget, slots=2)
         assert all(r.done for r in reqs)
         assert [len(r.generated) for r in reqs] == [budget] * 5
         assert eng.metrics["completed"] == 5

@@ -1,17 +1,9 @@
 """E7: serving-engine next-token selection — greedy vs temperature/top-k —
-plus sampling determinism (the module fallback rng) and the bounded LRU
-prefill-function cache.
+plus sampling determinism (the module fallback rng).
 """
 import numpy as np
 
-from repro.core.cache import LruCache
-from repro.serving.engine import (
-    EngineConfig,
-    OpaqueModelAdapter,
-    ServeEngine,
-    sample_token,
-    seed_sampler,
-)
+from repro.serving.engine import EngineConfig, ServeEngine, sample_token, seed_sampler
 
 
 def _logits(rng, vocab=32):
@@ -106,58 +98,3 @@ class TestEngineSelect:
         z = _logits(np.random.default_rng(10))
         allowed = set(np.argsort(z)[-3:].tolist())
         assert {eng._select(z) for _ in range(200)} <= allowed
-
-
-class TestPrefillCapacityDefault:
-    def test_default_covers_every_reachable_bucket(self):
-        from repro.serving.engine import _prefill_capacity
-
-        # prompts pad to multiples of prefill_bucket, capped by max_len —
-        # the default bound fits one jitted fn per reachable bucket
-        assert _prefill_capacity(EngineConfig(max_len=256, prefill_bucket=32)) == 8
-        assert _prefill_capacity(EngineConfig(max_len=1024, prefill_bucket=32)) == 32
-        assert _prefill_capacity(EngineConfig(max_len=16, prefill_bucket=32)) == 1
-
-    def test_explicit_bound_wins(self):
-        from repro.serving.engine import _prefill_capacity
-
-        assert _prefill_capacity(EngineConfig(max_len=1024, prefill_bucket=32, prefill_cache_size=4)) == 4
-
-
-class TestPrefillCacheBounded:
-    def _pair(self, capacity):
-        # _prefill_fn only touches cfg/compute_dtype inside the (untraced)
-        # closure and the cache — skip the heavy model setup; the engine stub
-        # carries just enough state for _sync_cache_metrics
-        ad = object.__new__(OpaqueModelAdapter)
-        ad.cfg = None
-        ad.compute_dtype = None
-        ad.prefill_cache = LruCache(capacity)
-        eng = object.__new__(ServeEngine)
-        eng.adapter = ad
-        eng._prefill_cache = ad.prefill_cache
-        eng.metrics = {}
-        return ad, eng
-
-    def test_repeat_bucket_reuses_jitted_fn(self):
-        ad, eng = self._pair(capacity=4)
-        f32 = ad._prefill_fn(32)
-        assert ad._prefill_fn(32) is f32
-        eng._sync_cache_metrics()
-        assert eng.metrics["prefill_cache_size"] == 1
-        assert eng.metrics["prefill_cache_evictions"] == 0
-        # uniform hit accounting: the engine surfaces LruCache's own
-        # hits/hit_rate, same numbers CompiledModel.cache_stats reports
-        assert eng.metrics["prefill_cache_hits"] == 1
-        assert eng.metrics["prefill_cache_hit_rate"] == ad.prefill_cache.hit_rate == 0.5
-
-    def test_lru_eviction_and_metrics(self):
-        ad, eng = self._pair(capacity=2)
-        f32 = ad._prefill_fn(32)
-        ad._prefill_fn(64)
-        ad._prefill_fn(96)  # evicts bucket 32
-        eng._sync_cache_metrics()
-        assert eng.metrics["prefill_cache_size"] == 2
-        assert eng.metrics["prefill_cache_evictions"] == 1
-        assert 32 not in ad.prefill_cache
-        assert ad._prefill_fn(32) is not f32  # rebuilt after eviction

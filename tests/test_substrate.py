@@ -174,18 +174,17 @@ class TestTrainLoop:
 
 class TestServeEngine:
     def test_continuous_batching_drains(self):
-        from repro.launch.serve import serve_demo
+        from repro.serving.engine import EngineConfig, Request, ServeEngine
+        from repro.serving.token_path import CompiledTokenAdapter, CompiledTokenPath, TokenPathConfig
 
-        reqs, eng = serve_demo("qwen3_1_7b", requests=5, prompt_len=12, new_tokens=4, slots=2)
+        tp = CompiledTokenPath(TokenPathConfig(), backend="ref", s_granularity=8)
+        eng = ServeEngine(ecfg=EngineConfig(slots=2, max_len=12 + 4 + 8), adapter=CompiledTokenAdapter(tp))
+        rng = np.random.default_rng(0)
+        reqs = [Request(uid=i, prompt=rng.integers(1, tp.cfg.vocab, (12,)).astype(np.int32), max_new_tokens=4)
+                for i in range(5)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
         assert all(r.done for r in reqs)
         assert all(len(r.generated) == 4 for r in reqs)
         assert eng.metrics["completed"] == 5
-
-    def test_int8_kv_serving_matches_bf16_greedy_mostly(self):
-        from repro.launch.serve import serve_demo
-
-        r16, _ = serve_demo("minicpm_2b", requests=3, prompt_len=10, new_tokens=4, slots=3, seed=1)
-        r8, _ = serve_demo("minicpm_2b", requests=3, prompt_len=10, new_tokens=4, slots=3, int8_kv=True, seed=1)
-        # same prompts, greedy decode: int8 cache should agree on most tokens
-        agree = sum(int(a.generated[0] == b.generated[0]) for a, b in zip(r16, r8))
-        assert agree >= 2, [r.generated for r in r16 + r8]

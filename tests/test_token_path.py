@@ -14,6 +14,7 @@ shared-PlanCache one-specialization-per-cell) and the fused attention lane
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -257,10 +258,18 @@ class TestPlanDiffStates:
 
 
 class TestSharedCacheServing:
-    def test_one_specialization_per_visited_cell(self):
+    # (slots, max_len, prefill_bucket): on-bucket, then off-bucket N (3 slots
+    # run at N = 4), off-bucket S (20 positions run at S = 24) and a prompt
+    # bucket (4) off the S granularity (8)
+    EXTENTS = [(1, 16, 8), (3, 16, 8), (1, 20, 4), (3, 20, 4)]
+
+    @pytest.mark.parametrize("slots,max_len,prefill_bucket", [(2, 16, 8), (3, 20, 4)])
+    def test_one_specialization_per_visited_cell(self, slots, max_len, prefill_bucket):
         tp = _tp("ref")
+        trips = default_registry().counter("tokenpath.cache.host_trips")
+        before = trips.value
         eng = ServeEngine(
-            ecfg=EngineConfig(slots=2, max_len=16, prefill_bucket=8),
+            ecfg=EngineConfig(slots=slots, max_len=max_len, prefill_bucket=prefill_bucket),
             adapter=CompiledTokenAdapter(tp),
         )
         rng = np.random.default_rng(5)
@@ -274,49 +283,75 @@ class TestSharedCacheServing:
             )
         eng.run_until_drained()
         stats = tp.cache_stats()
-        # one prefill cell (N=1, S=8) + one decode cell (N=2, S=16): every
-        # other prefill/decode step is a cache hit — zero re-lowering
+        # one prefill cell (N=1, S=8) + one decode cell at the buckets of
+        # (slots, max_len): every other prefill/decode step is a cache hit —
+        # zero re-lowering
+        assert set(tp.plan_cache.keys()) == {
+            (tp.prefill_model.graph.name, (("N", 1), ("S", 8))),
+            (tp.decode_model.graph.name, (("N", tp.decode_cm.bucket_for("N", slots)),
+                                          ("S", tp.decode_cm.bucket_for("S", max_len)))),
+        }
         assert stats["misses"] == 2
         assert stats["size"] == 2
         assert stats["hits"] == eng.metrics["prefills"] + eng.metrics["decode_steps"] - 2
         assert all(r.done for r in eng.active.values()) or not eng.active
+        assert trips.value == before
 
-    def test_engine_matches_mirror_generation(self):
-        """Greedy generation through the engine == hand-rolled jnp-mirror loop."""
+    @pytest.mark.parametrize("slots,max_len,prefill_bucket", EXTENTS)
+    def test_engine_matches_mirror_generation(self, slots, max_len, prefill_bucket):
+        """Greedy generation through the engine == hand-rolled jnp-mirror
+        loop, one request per slot, at on- and off-bucket engine extents."""
         tp = _tp("ref")
         eng = ServeEngine(
-            ecfg=EngineConfig(slots=1, max_len=16, prefill_bucket=8),
+            ecfg=EngineConfig(slots=slots, max_len=max_len, prefill_bucket=prefill_bucket),
             adapter=CompiledTokenAdapter(tp),
         )
-        prompt = np.array([5, 9, 2], np.int32)
-        req = Request(uid=0, prompt=prompt, max_new_tokens=4)
-        eng.submit(req)
+        prompts = [np.array([5, 9, 2], np.int32), np.array([7, 1, 3, 3, 8], np.int32), np.array([11], np.int32)]
+        reqs = [Request(uid=i, prompt=p, max_new_tokens=4) for i, p in enumerate(prompts[:slots])]
+        for req in reqs:
+            eng.submit(req)
         eng.run_until_drained()
 
         # mirror: prefill at the bucket length, then decode token by token
-        bucket, s_max, plen = 8, 16, len(prompt)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :plen] = prompt
-        jl, jcaches = prefill_jax(CFG, PARAMS, padded, causal_mask(1, bucket))
-        states = []
-        for k, v in jcaches:
-            ks = np.zeros((1, s_max, CFG.d_model), np.int8)
-            vs = np.zeros((1, s_max, CFG.d_model), np.int8)
-            ks[:, :bucket] = np.asarray(k)
-            vs[:, :bucket] = np.asarray(v)
-            states.append((ks, vs))
-        toks = [int(np.asarray(jl)[0, plen - 1].argmax())]
-        pos = plen
-        for _ in range(3):
-            onehot = np.zeros((1, s_max, 1), np.int8)
-            onehot[0, pos, 0] = 1
-            mask = (np.arange(s_max)[None, None, :] <= pos).astype(np.float32)
-            jl, states = decode_jax(
-                CFG, PARAMS, np.array([[toks[-1]]], np.int32), onehot, mask, states
-            )
-            toks.append(int(np.asarray(jl)[0, 0].argmax()))
-            pos += 1
-        assert req.generated == toks
+        s_max = max_len
+        for req in reqs:
+            prompt = req.prompt
+            plen = len(prompt)
+            bucket = -(-plen // prefill_bucket) * prefill_bucket
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :plen] = prompt
+            jl, jcaches = prefill_jax(CFG, PARAMS, padded, causal_mask(1, bucket))
+            states = []
+            for k, v in jcaches:
+                ks = np.zeros((1, s_max, CFG.d_model), np.int8)
+                vs = np.zeros((1, s_max, CFG.d_model), np.int8)
+                ks[:, :bucket] = np.asarray(k)
+                vs[:, :bucket] = np.asarray(v)
+                states.append((ks, vs))
+            toks = [int(np.asarray(jl)[0, plen - 1].argmax())]
+            pos = plen
+            for _ in range(3):
+                onehot = np.zeros((1, s_max, 1), np.int8)
+                onehot[0, pos, 0] = 1
+                mask = (np.arange(s_max)[None, None, :] <= pos).astype(np.float32)
+                jl, states = decode_jax(
+                    CFG, PARAMS, np.array([[toks[-1]]], np.int32), onehot, mask, states
+                )
+                toks.append(int(np.asarray(jl)[0, 0].argmax()))
+                pos += 1
+            assert req.generated == toks
+        # every decode ran the jitted step at the buckets of (slots, max_len)
+        cm = tp.decode_cm
+        assert list(tp._step_fns) == [(cm.bucket_for("N", slots), cm.bucket_for("S", max_len))]
+
+    @pytest.mark.parametrize("n,s,need", [(3, 16, "(4, 16)"), (2, 20, "(2, 24)")],
+                             ids=["off-bucket-N", "off-bucket-S"])
+    def test_decode_step_refuses_an_off_bucket_cache(self, n, s, need):
+        tp = _tp("ref")
+        cache = jax.device_put(tp.init_cache(n, s))
+        with pytest.raises(ValueError, match=re.escape(f"needs {need}")):
+            tp.decode_step(np.ones((n, 1), np.int32), np.zeros((n,), np.int32), cache)
+        assert not tp._step_fns and tp.cache_stats()["misses"] == 0
 
 
 #: Each token-path span and the span it opens inside.
@@ -593,17 +628,17 @@ class TestDeviceScatter:
         tp = _tp("ref")
         ad = CompiledTokenAdapter(tp)
         # every jit of ``_write_rows`` shares one cache, so the shapes here are
-        # this test's own: three slots of 24 positions
-        cache = ad.init_cache(3, 24)
+        # this test's own: four slots (a bucket) of 40 positions
+        cache = ad.init_cache(4, 40)
         rng = np.random.default_rng(23)
         programs = []
-        for slot in (0, 1, 2):
+        for slot in (0, 1, 2, 3):
             rows = {k: rng.integers(-128, 128, (1, 8, CFG.d_model)).astype(np.int8) for k in cache}
             cache = ad.scatter(cache, slot, rows)
             programs.append(ad._write_rows._cache_size())
-        assert programs == programs[:1] * 3
+        assert programs == programs[:1] * 4
         with pytest.raises(IndexError):  # never clamped onto the last slot
-            ad.scatter(cache, 3, rows)
+            ad.scatter(cache, 4, rows)
 
 
 class TestDevicePrefill:
@@ -679,7 +714,7 @@ class TestDevicePrefill:
         assert eng.metrics["prefills"] == 4
         assert fetched.value - before == eng.metrics["prefills"] * CFG.vocab * 4
 
-    def test_an_off_bucket_prompt_falls_back_to_the_host_prefill(self):
+    def test_an_off_bucket_prompt_runs_the_jitted_step_at_its_bucket(self):
         tp = _tp("ref")  # S buckets of 8: a prompt padded to 4 is off bucket
         fetched = default_registry().counter(self.FETCHED)
         before = fetched.value
@@ -688,10 +723,11 @@ class TestDevicePrefill:
         logits, cache = tp.prefill(padded, causal_mask(1, 4))
         np.testing.assert_array_equal(row, logits[0, 2])
         for name in cache:
-            np.testing.assert_array_equal(np.asarray(rows[name]), cache[name])
-        assert not tp._prefill_fns
-        # the whole logits and the K/V rows came to the host
-        assert fetched.value - before == logits.nbytes + sum(v.nbytes for v in cache.values())
+            assert isinstance(rows[name], jax.Array) and rows[name].shape == (1, 8, CFG.d_model)
+            np.testing.assert_array_equal(np.asarray(rows[name])[:, :4], cache[name])
+        assert list(tp._prefill_fns) == [8]
+        # one logits row came to the host, nothing else
+        assert fetched.value - before == CFG.vocab * 4
 
     @pytest.mark.parametrize("plen", [0, 9])
     def test_plen_outside_the_bucket_is_refused(self, plen):
